@@ -38,8 +38,8 @@ def bench_flash_attention():
     rng = np.random.default_rng(1)
     B, S, H, K, D = 2, 64, 4, 2, 32
     q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, S, K, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, S, K, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, K, S, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, K, S, D)), jnp.float32)
     qpos = jnp.broadcast_to(jnp.arange(S), (B, S))
     want = np.asarray(ref.attention_ref(q, k, v, offset=0, kv_valid_len=S))
     for bq, bkv in ((16, 16), (32, 32)):

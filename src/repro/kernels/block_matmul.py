@@ -27,9 +27,8 @@ def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -47,9 +46,17 @@ def _pad_to(x: jax.Array, mult: tuple[int, int]) -> jax.Array:
 def block_matmul_2d(x: jax.Array, w: jax.Array, *, bm: int = 256,
                     bk: int = 512, bn: int = 256,
                     interpret: bool = False) -> jax.Array:
-    """x (M,K) @ w (K,N) -> (M,N) with explicit VMEM tiling."""
+    """x (M,K) @ w (K,N) -> (M,N) with explicit VMEM tiling.
+
+    Compiled (non-interpret) calls round every tile up to the chip's
+    tiling first — bm to a multiple of 8, bk and bn to multiples of 128 —
+    so any tile table (built-in levels, a compiled VersionSet, an
+    autotuned ladder) compiles; interpret mode keeps the requested tiles
+    so tests can exercise small ones."""
     m0, k0 = x.shape
     _, n0 = w.shape
+    if not interpret:
+        bm, bk, bn = _chip_tiles(bm, bk, bn)
     bm, bk, bn = min(bm, _ceil_mult(m0, 8)), min(bk, _ceil_mult(k0, 128)), \
         min(bn, _ceil_mult(n0, 128))
     xp = _pad_to(x, (bm, bk))
@@ -68,12 +75,19 @@ def block_matmul_2d(x: jax.Array, w: jax.Array, *, bm: int = 256,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="block_matmul",
     )(xp, wp)
     return out[:m0, :n0]
 
 
 def _ceil_mult(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
+
+
+def _chip_tiles(bm: int, bk: int, bn: int) -> tuple[int, int, int]:
+    """(bm, bk, bn) rounded up to the TPU tiling: the x block (bm, bk)
+    and w block (bk, bn) need their last two dims divisible by (8, 128)."""
+    return _ceil_mult(bm, 8), _ceil_mult(bk, 128), _ceil_mult(bn, 128)
 
 
 def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int = 2) -> int:

@@ -44,7 +44,7 @@ def flash_attention_paged(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                           kv_valid_len, window=None, softcap=None,
                           interpret: bool = False) -> jax.Array:
     """Adapter for the page-table decode kernel: k/v are physical page
-    pools (P, page_size, K, D) and ``page_table`` (B, pages_per_slot)
+    pools (P, K, page_size, D) and ``page_table`` (B, pages_per_slot)
     maps each row's logical pages.  No tile knob — the page size IS the
     kv block size (one page per DMA), so adaptive tile tables don't
     shape this op."""

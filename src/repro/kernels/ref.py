@@ -12,7 +12,8 @@ def matmul_ref(x: jax.Array, w: jax.Array) -> jax.Array:
 def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   offset, kv_valid_len, window: int | None = None,
                   softcap: float | None = None) -> jax.Array:
-    """Same contract as kernels.flash_attention (query i at offset+i)."""
+    """Same contract as kernels.flash_attention (query i at offset+i;
+    k/v heads-major (B, K, T, D))."""
     from repro.models.layers import attend
     b, s = q.shape[:2]
     qpos = jnp.asarray(offset, jnp.int32) + jnp.arange(s, dtype=jnp.int32)

@@ -6,6 +6,15 @@ iteration is sequential, so the inter-chunk recurrence needs no extra pass.
 Per chunk the work is three small MXU matmuls ((Q,N)x(N,Q), (Q,Q)x(Q,P),
 (N,Q)x(Q,P)): the "duality" that makes SSDs MXU-friendly.
 
+Layout is heads-major: x/b/c are transposed to (B, H, L, ·) here, so every
+block is ``(1, 1, Q, ·)`` whose last two dims are (Q, full width) — the
+shape the TPU compiler accepts.  The per-position log-decay prefix sums
+``seg`` are computed outside the kernel (the TPU kernel language has no
+cumsum) and enter twice: as a (Q, 1) column and as a (1, Q) row, the two
+orientations the intra-chunk decay matrix exp(seg_i - seg_j) needs.  The
+step size dt is folded into B (``C (dt*B)^T == (C B^T) diag(dt)``), so it
+enters only as a column.
+
 The chunk size Q trades VMEM locality (larger intra-chunk matmuls, fewer
 state round-trips) against parallel grid width — the SSD variant knob used
 by the adaptive compiler for the mamba2/recurrentgemma cells.
@@ -19,8 +28,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_CONTRACT_LAST = (((1,), (1,)), ((), ()))    # a (m,k), b (n,k) -> (m,n)
+_CONTRACT_FIRST = (((0,), (0,)), ((), ()))   # a (k,m), b (k,n) -> (m,n)
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
+
+def _ssd_kernel(x_ref, segc_ref, segr_ref, dtc_ref, b_ref, c_ref, h0_ref,
                 y_ref, state_ref, h_scratch, *, n_chunks: int, q: int):
     ci = pl.program_id(2)
 
@@ -28,36 +40,36 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
     def _init():
         h_scratch[...] = h0_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (Q,)
-    a = a_ref[0]                                     # scalar decay rate (<0)
-    bm = b_ref[0, :, 0, :].astype(jnp.float32)       # (Q, N)
-    cm = c_ref[0, :, 0, :].astype(jnp.float32)       # (Q, N)
-
-    da = dt * a                                      # (Q,) log-decay
-    seg = jnp.cumsum(da)                             # inclusive
-    total = seg[-1]
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    seg_c = segc_ref[0, 0]                           # (Q, 1) inclusive
+    seg_r = segr_ref[0, 0, 0]                        # (1, Q) same values
+    dt_c = dtc_ref[0, 0]                             # (Q, 1)
+    bm = b_ref[0, 0].astype(jnp.float32)             # (Q, N)
+    cm = c_ref[0, 0].astype(jnp.float32)             # (Q, N)
+    # (1, 1) chunk decay = seg at the last position, picked by a masked
+    # sum (exact: every other term is 0) rather than a lane-offset slice
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1) == q - 1
+    total = jnp.sum(jnp.where(last, seg_r, 0.0), axis=1, keepdims=True)
 
     # intra-chunk (attention-like masked matmul)
     i_pos = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     j_pos = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    decay = jnp.exp(seg[:, None] - seg[None, :])
-    gate = jnp.where(j_pos <= i_pos, decay, 0.0)
-    cb = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)   # (Q,Q)
-    m_att = cb * gate * dt[None, :]
-    y = jnp.dot(m_att, x, preferred_element_type=jnp.float32)    # (Q,P)
+    gate = jnp.where(j_pos <= i_pos, jnp.exp(seg_c - seg_r), 0.0)
+    cb = jax.lax.dot_general(cm, bm * dt_c, _CONTRACT_LAST,
+                             preferred_element_type=jnp.float32)  # (Q,Q)
+    y = jnp.dot(cb * gate, x, preferred_element_type=jnp.float32)  # (Q,P)
 
     # inter-chunk: y += exp(seg_i) * C_i . h_in   (h (P,N))
     h = h_scratch[...]
-    y += jnp.exp(seg)[:, None] * jnp.dot(
-        cm, h.T, preferred_element_type=jnp.float32)
+    y += jnp.exp(seg_c) * jax.lax.dot_general(
+        cm, h, _CONTRACT_LAST, preferred_element_type=jnp.float32)
 
     # state update: h' = exp(total) h + X^T (w * B),  w_j = exp(total-seg_j)dt_j
-    w = jnp.exp(total - seg) * dt                    # (Q,)
-    h_scratch[...] = jnp.exp(total) * h + jnp.dot(
-        x.T, bm * w[:, None], preferred_element_type=jnp.float32)
+    w = jnp.exp(total - seg_c) * dt_c                # (Q, 1)
+    h_scratch[...] = jnp.exp(total) * h + jax.lax.dot_general(
+        x, bm * w, _CONTRACT_FIRST, preferred_element_type=jnp.float32)
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _flush():
@@ -88,26 +100,42 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     h0 = (jnp.zeros((bsz, h, p, n), jnp.float32) if initial_state is None
           else initial_state.astype(jnp.float32))
 
+    # heads-major per-position scalars: dt and the in-chunk inclusive
+    # prefix sums of dt*a, as (B,H,L,1) columns and (B,H,NC,1,Q) rows
+    dth = jnp.swapaxes(dt.astype(jnp.float32), 1, 2)          # (B,H,L)
+    seg = jnp.cumsum(
+        (dth * a.astype(jnp.float32)[None, :, None]).reshape(
+            bsz, h, n_chunks, q), axis=-1)                    # (B,H,NC,Q)
+    seg_c = seg.reshape(bsz, h, l, 1)
+    seg_r = seg.reshape(bsz, h, n_chunks, 1, q)
+    dt_c = dth.reshape(bsz, h, l, 1)
+
+    def heads_major(t):
+        return jnp.swapaxes(t, 1, 2)                          # (B,H,L,·)
+
     y, state = pl.pallas_call(
         functools.partial(_ssd_kernel, n_chunks=n_chunks, q=q),
         grid=(bsz, h, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, q, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, q, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, q, 1, n), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, q, 1, n), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, q, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, 1, q),
+                         lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, q, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, q, n), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, q, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, l, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, l, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt.astype(jnp.float32), a.astype(jnp.float32), b, c, h0)
-    return y[:, :orig_l], state
+        name="ssd_scan",
+    )(heads_major(x), seg_c, seg_r, dt_c, heads_major(b), heads_major(c), h0)
+    return jnp.swapaxes(y, 1, 2)[:, :orig_l], state

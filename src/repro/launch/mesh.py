@@ -3,17 +3,22 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax use.
+
+Axes are ``Auto``: ``dist.sharding.hint`` places sharding constraints,
+which JAX accepts only on Auto axes (``make_mesh`` defaults to Explicit).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips single pod; 2x16x16 = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
@@ -21,4 +26,5 @@ def make_debug_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import Request, ServingEngine
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))
     model = build_model(cfg)

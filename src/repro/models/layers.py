@@ -7,7 +7,8 @@ Every layer is a pair of functions:
 Conventions:
   x           (B, S, M)    activations, bf16
   q           (B, S, H, D)
-  k, v        (B, T, K, D) K = kv heads
+  k, v        (B, K, T, D) K = kv heads; heads-major, the KV cache layout
+              the Pallas attention kernels read without a transpose
   positions   (B, S) int32, or (3, B, S) for M-RoPE
   softmax / norms / rope run in fp32 and cast back.
 
@@ -128,11 +129,10 @@ ANALYSIS_UNROLL = False
 def _attend_core(q, k, v, *, q_positions, kv_valid_len, window, softcap):
     from repro.dist.sharding import hint
     b, s, h, d = q.shape
-    t = k.shape[1]
-    kh = k.shape[2]
+    kh, t = k.shape[1], k.shape[2]
     g = h // kh
     qf = q.reshape(b, s, kh, g, d).astype(jnp.float32) * (d ** -0.5)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qf, k.astype(jnp.float32))
+    scores = jnp.einsum("bskgd,bktd->bkgst", qf, k.astype(jnp.float32))
     # keep scores sharded like the KV sequence (stops GSPMD from
     # all-gathering a seq-sharded cache; softmax runs as partial max/sum)
     scores = hint(scores, ("batch", None, None, None, "seq"))
@@ -148,7 +148,7 @@ def _attend_core(q, k, v, *, q_positions, kv_valid_len, window, softcap):
         mask &= j < kvl.reshape(-1, 1, 1) if kvl.ndim else j < kvl
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgst,bktd->bskgd", probs, v.astype(jnp.float32))
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
@@ -168,7 +168,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
            use_kernel_hook: bool = True) -> jax.Array:
     """Masked GQA attention.
 
-    q: (B, S, H, D); k/v: (B, T, K, D).  q_positions (B, S): absolute position
+    q: (B, S, H, D); k/v: (B, K, T, D).  q_positions (B, S): absolute position
     of each query token (so decode passes S=1 with its position).  kv slot j
     holds absolute position j; slots >= kv_valid_len are invalid (future cache
     slots).  Causal: attend to j <= q_pos; window w: j > q_pos - w.
@@ -184,7 +184,7 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       kv_valid_len=kv_valid_len, window=window,
                       softcap=softcap)
     b, s, _, _ = q.shape
-    t = k.shape[1]
+    t = k.shape[2]
     if s * t <= SCORE_CHUNK_ELEMS or s == 1:
         return _attend_core(q, k, v, q_positions=q_positions,
                             kv_valid_len=kv_valid_len, window=window,
@@ -214,14 +214,14 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
               ) -> tuple[jax.Array, dict | None]:
     """Self-attention with optional KV cache.
 
-    cache: {"k": (B, Tmax, K, D), "v": ...}; cache_index: absolute position
+    cache: {"k": (B, K, Tmax, D), "v": ...}; cache_index: absolute position
     of the first new token (0 for prefill-from-empty) — a scalar int32, or
     a (B,) int32 vector when batch rows sit at different positions
     (continuous batching: each serving slot decodes at its own position
     with its own kv-valid horizon).  Returns (y, updated_cache).
 
     With ``page_table`` (B, pages_per_slot) the cache leaves are physical
-    page pools ``(n_pages + 1, page_size, K, D)``: the new token's KV is
+    page pools ``(n_pages + 1, K, page_size, D)``: the new token's KV is
     scattered into its slot's page at ``cache_index``, and attention reads
     through the table (a scalar-prefetched Pallas kernel when a paged
     kernel is dispatched, a pool gather on the XLA reference path).
@@ -238,20 +238,21 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
         k = apply_rope(k, positions, cfg.rope_theta, mrope)
     qpos = positions[-1] if positions.ndim == 3 else positions  # t-axis for mrope
     if cache is None:
-        y = attend(q, k, v, q_positions=qpos, kv_valid_len=s,
+        y = attend(q, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                   q_positions=qpos, kv_valid_len=s,
                    window=cfg.sliding_window)
         new_cache = None
     elif page_table is not None:
         idx = jnp.broadcast_to(
             jnp.asarray(cache_index, jnp.int32).reshape(-1), (b,))
-        ps_sz = cache["k"].shape[1]
+        ps_sz = cache["k"].shape[2]
         if s == 1:
             bidx = jnp.arange(b, dtype=jnp.int32)
             phys = page_table[bidx, idx // ps_sz]   # (B,) physical page
             off = idx % ps_sz
-            ck = cache["k"].at[phys, off].set(
+            ck = cache["k"].at[phys, :, off].set(
                 k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[phys, off].set(
+            cv = cache["v"].at[phys, :, off].set(
                 v[:, 0].astype(cache["v"].dtype))
         else:
             # multi-token (speculative verify): scatter each row's S new
@@ -261,8 +262,8 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
             bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
             phys = page_table[bidx, rows // ps_sz]  # (B,S)
             off = rows % ps_sz
-            ck = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype))
+            ck = cache["k"].at[phys, :, off].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[phys, :, off].set(v.astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         from repro.kernels import dispatch
         fn = dispatch.get_paged_attention() if s == 1 else None
@@ -271,11 +272,12 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
                    kv_valid_len=idx + 1, window=cfg.sliding_window,
                    softcap=None)
         else:
-            n_slot = page_table.shape[1]
-            kd = ck[page_table].reshape(b, n_slot * ps_sz, *ck.shape[2:])
-            vd = cv[page_table].reshape(b, n_slot * ps_sz, *cv.shape[2:])
-            y = attend(q, kd, vd, q_positions=qpos, kv_valid_len=idx + s,
-                       window=cfg.sliding_window, use_kernel_hook=False)
+            def gather(pool):               # (B, K, n_slot * ps, D)
+                pages = jnp.moveaxis(pool[page_table], 1, 2)
+                return pages.reshape(b, pool.shape[1], -1, pool.shape[3])
+            y = attend(q, gather(ck), gather(cv), q_positions=qpos,
+                       kv_valid_len=idx + s, window=cfg.sliding_window,
+                       use_kernel_hook=False)
     else:
         idx = jnp.asarray(cache_index, jnp.int32)
         if idx.ndim:
@@ -283,13 +285,15 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
             # index; kv-valid horizon is per-row too
             rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
             bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            ck = cache["k"].at[bidx, rows].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, rows].set(v.astype(cache["v"].dtype))
+            ck = cache["k"].at[bidx, :, rows].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[bidx, :, rows].set(v.astype(cache["v"].dtype))
         else:
             ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, idx, 0, 0))
+                cache["k"], jnp.swapaxes(k, 1, 2).astype(cache["k"].dtype),
+                (0, 0, idx, 0))
             cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, idx, 0, 0))
+                cache["v"], jnp.swapaxes(v, 1, 2).astype(cache["v"].dtype),
+                (0, 0, idx, 0))
         y = attend(q, ck, cv, q_positions=qpos, kv_valid_len=idx + s,
                    window=cfg.sliding_window)
         new_cache = {"k": ck, "v": cv}

@@ -101,12 +101,14 @@ def _attn_cache_specs(cfg: ModelConfig, batch: int, t_max: int) -> dict:
                 "k_rope": ParamSpec((batch, t_max, 1, dr), jnp.bfloat16,
                                     ("batch", "seq", None, "head_dim"),
                                     init="zeros")}
+    # heads-major (B, K, T, D): the layout the Pallas attention kernels
+    # read block-by-block, so decode never transposes the cache
     k, d = cfg.num_kv_heads, cfg.head_dim
-    return {"k": ParamSpec((batch, t_max, k, d), jnp.bfloat16,
-                           ("batch", "seq", "kv_heads", "head_dim"),
+    return {"k": ParamSpec((batch, k, t_max, d), jnp.bfloat16,
+                           ("batch", "kv_heads", "seq", "head_dim"),
                            init="zeros"),
-            "v": ParamSpec((batch, t_max, k, d), jnp.bfloat16,
-                           ("batch", "seq", "kv_heads", "head_dim"),
+            "v": ParamSpec((batch, k, t_max, d), jnp.bfloat16,
+                           ("batch", "kv_heads", "seq", "head_dim"),
                            init="zeros")}
 
 
@@ -227,8 +229,8 @@ def _local_attention(cfg: ModelConfig, params: dict, x: jax.Array, *,
         y, new_cache = rg_mod.window_attention_chunk(q, cache, k, v, t,
                                                      valid_len, window)
     else:
-        y = L.attend(q, k, v, q_positions=positions, kv_valid_len=s,
-                     window=window)
+        y = L.attend(q, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                     q_positions=positions, kv_valid_len=s, window=window)
         new_cache = (rg_mod.fill_window_cache(cache, k, v, window)
                      if cache is not None else None)
     return jnp.einsum("bshd,hdm->bsm", y, params["wo"].astype(x.dtype)), \
@@ -323,14 +325,23 @@ class Model:
         those whose spec carries a ``"seq"`` axis (attention k/v, MLA
         c_kv/k_rope).  Recurrent state (SSM/RG-LRU) and the local-window
         ring cache are O(1)-or-O(window) per slot and stay dense."""
-        cached = getattr(self, "_paged_paths", None)
+        return frozenset(self.paged_leaf_axes())
+
+    def paged_leaf_axes(self) -> dict:
+        """Key-path -> (batch axis, seq axis) of every pageable leaf, in
+        its dense layout.  In the leaf's page pool the batch axis indexes
+        physical pages and the seq axis holds one page's positions
+        (:meth:`paged_cache_specs`); the serving engine moves rows in and
+        out of pages along these two axes."""
+        cached = getattr(self, "_paged_axes", None)
         if cached is None:
             flat, _ = jax.tree_util.tree_flatten_with_path(
                 self.cache_specs(1, 8),
                 is_leaf=lambda x: isinstance(x, ParamSpec))
-            cached = frozenset(path_keys(p) for p, s in flat
-                               if "seq" in s.axes)
-            self._paged_paths = cached
+            cached = {path_keys(p): (s.axes.index("batch"),
+                                     s.axes.index("seq"))
+                      for p, s in flat if "seq" in s.axes}
+            self._paged_axes = cached
         return cached
 
     def all_cache_leaves_paged(self) -> bool:
@@ -346,10 +357,13 @@ class Model:
     def paged_cache_specs(self, batch: int, t_max: int, n_pages: int,
                           page_size: int) -> dict:
         """Cache specs with every ``"seq"``-axis leaf reshaped from dense
-        rows ``(batch, t_max, ...)`` to a physical page pool
-        ``(n_pages + 1, page_size, ...)`` (index 0 = pinned trash page).
-        One logical page uses the same physical index in every layer's
-        pool, so a single per-slot page table addresses all layers."""
+        rows to a physical page pool: the batch axis becomes
+        ``n_pages + 1`` physical pages (index 0 = pinned trash page) and
+        the seq axis ``page_size`` — ``(B, K, T, D)`` attention rows give
+        ``(n_pages + 1, K, page_size, D)`` pools, ``(B, T, r)`` MLA
+        latents ``(n_pages + 1, page_size, r)``.  One logical page uses
+        the same physical index in every layer's pool, so a single
+        per-slot page table addresses all layers."""
         if t_max % page_size:
             raise ValueError(f"t_max={t_max} must be a multiple of "
                              f"page_size={page_size}")
@@ -357,12 +371,12 @@ class Model:
         def to_pool(spec):
             if not isinstance(spec, ParamSpec) or "seq" not in spec.axes:
                 return spec
-            si = spec.axes.index("seq")
+            bi, si = spec.axes.index("batch"), spec.axes.index("seq")
             shape = list(spec.shape)
-            shape[si - 1] = n_pages + 1        # batch axis -> physical pages
+            shape[bi] = n_pages + 1            # batch axis -> physical pages
             shape[si] = page_size
             axes = list(spec.axes)
-            axes[si - 1], axes[si] = "pages", None
+            axes[bi], axes[si] = "pages", None
             return ParamSpec(tuple(shape), spec.dtype, tuple(axes),
                              init="zeros")
 

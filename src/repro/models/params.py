@@ -27,6 +27,7 @@ Logical axis vocabulary (used by the sharding rules):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Any, Callable
 
@@ -72,7 +73,10 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _init_one(spec: ParamSpec, key: jax.Array) -> jax.Array:
+    # one compiled program per distinct spec: the float32 draw, the scale
+    # and the cast fuse, so a large bf16 leaf never exists in float32
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":

@@ -72,12 +72,29 @@ def _next_pow2(n: int) -> int:
 
 # Built-in interference-level -> tile table (one entry per grid level).
 # Low pressure: big tiles, maximal reuse of the shared cache; high
-# pressure: small private-cache-resident tiles that cede the LLC.
-_LEVEL_TILE_SIZES = (256, 224, 192, 160, 128, 112, 96, 80, 64, 48)
+# pressure: small private-cache-resident tiles that cede the LLC.  Every
+# level is a distinct tile set the TPU compiler accepts as written: bm is
+# a multiple of 16 (bf16 sublane tiling), bk and bn multiples of 128 (lane
+# tiling), bkv a power of two that divides any power-of-two cache length,
+# and the matmul working set shrinks strictly with the level.  Serving
+# matmuls have M = batch x chunk <= 16 rows, so bm clamps there and the
+# (bk, bn) pairs, all distinct, carry the level.
+_LEVEL_TILES = (   # (bm, bk, bn, bkv)
+    (256, 1024, 512, 512),
+    (224, 768, 512, 512),
+    (192, 512, 512, 512),
+    (160, 512, 384, 512),
+    (128, 512, 256, 256),
+    (112, 384, 256, 256),
+    (96, 256, 256, 256),
+    (80, 384, 128, 128),
+    (64, 256, 128, 128),
+    (48, 128, 128, 128),
+)
 DEFAULT_LEVEL_TILES = tuple(
-    {"matmul": {"bm": s, "bk": 2 * s, "bn": s},
-     "attention": {"bq": max(s, 64), "bkv": max(2 * s, 128)}}
-    for s in _LEVEL_TILE_SIZES)
+    {"matmul": {"bm": bm, "bk": bk, "bn": bn},
+     "attention": {"bq": max(bm, 64), "bkv": bkv}}
+    for bm, bk, bn, bkv in _LEVEL_TILES)
 assert len(DEFAULT_LEVEL_TILES) == cm.NUM_LEVELS
 
 
@@ -510,20 +527,14 @@ class ServingEngine:
                                                   axis=cache_batch_axis(p)),
                 self.cache)
         paths = self._paged_paths
-        max_len = self.max_len
 
-        def f(p, c):
-            keys = path_keys(p)
-            if keys in paths:
-                if keys[0] == "blocks":
-                    shape = (c.shape[0], 1, max_len, *c.shape[3:])
-                else:
-                    shape = (1, max_len, *c.shape[2:])
-                return jnp.zeros(shape, c.dtype)
+        def f(p, c, empty):
+            if path_keys(p) in paths:
+                return empty
             return jax.lax.slice_in_dim(c, slot, slot + 1,
                                         axis=cache_batch_axis(p))
         body = {k: v for k, v in self.cache.items() if k != "page_table"}
-        return jax.tree_util.tree_map_with_path(f, body)
+        return jax.tree_util.tree_map_with_path(f, body, self._empty_row)
 
     @staticmethod
     def _make_row_writer():
@@ -548,7 +559,7 @@ class ServingEngine:
         recurrent state of hybrid models — land on their batch axis as in
         the dense writer.  The device page table passes through
         untouched (it is host-owned, refreshed by ``_sync_table``)."""
-        paths = self._paged_paths
+        axes = self.model.paged_leaf_axes()
         n_slot, ps = self.pages_per_slot, self.page_size
 
         def write(cache, row_cache, slot, wtab):
@@ -556,12 +567,16 @@ class ServingEngine:
 
             def put(p, c, r):
                 keys = path_keys(p)
-                if keys in paths:
-                    if keys[0] == "blocks":
-                        rp = r.reshape(r.shape[0], n_slot, ps, *r.shape[3:])
-                        return c.at[:, wtab].set(rp.astype(c.dtype))
-                    rp = r.reshape(n_slot, ps, *r.shape[2:])
-                    return c.at[wtab].set(rp.astype(c.dtype))
+                if keys in axes:
+                    # dense row: batch (size 1) at ba, max_len at sa ->
+                    # pages (n_slot) at ba, page positions (ps) at sa
+                    ba, sa = axes[keys]
+                    r = jnp.squeeze(r, ba)
+                    r = r.reshape(*r.shape[:sa - 1], n_slot, ps,
+                                  *r.shape[sa:])
+                    rp = jnp.moveaxis(r, sa - 1, ba)
+                    at = (slice(None),) * ba + (wtab,)
+                    return c.at[at].set(rp.astype(c.dtype))
                 return jax.lax.dynamic_update_slice_in_dim(
                     c, r.astype(c.dtype), slot, axis=cache_batch_axis(p))
             out = jax.tree_util.tree_map_with_path(put, body, row_cache)
@@ -577,22 +592,20 @@ class ServingEngine:
         sits at positions the remaining chunks overwrite before any query
         attends to it.  Dense leaves keep the pristine empty row's
         state."""
-        paths = self._paged_paths
-        n_slot, ps = self.pages_per_slot, self.page_size
+        axes = self.model.paged_leaf_axes()
 
         def gather(cache, row_cache, trow):
             body = {k: v for k, v in cache.items() if k != "page_table"}
 
             def g(p, c, r):
                 keys = path_keys(p)
-                if keys not in paths:
+                if keys not in axes:
                     return r
-                if keys[0] == "blocks":
-                    return c[:, trow].reshape(
-                        c.shape[0], 1, n_slot * ps,
-                        *c.shape[3:]).astype(r.dtype)
-                return c[trow].reshape(1, n_slot * ps,
-                                       *c.shape[2:]).astype(r.dtype)
+                # pool pages (n_slot) at ba, page positions at sa -> move
+                # the pages next to the positions and merge into max_len
+                ba, sa = axes[keys]
+                pages = jnp.moveaxis(jnp.take(c, trow, axis=ba), ba, sa - 1)
+                return pages.reshape(r.shape).astype(r.dtype)
             return jax.tree_util.tree_map_with_path(g, body, row_cache)
         return jax.jit(gather)
 
@@ -602,14 +615,14 @@ class ServingEngine:
         same physical index in every layer's pool).  Traced scalars, so
         one executable serves every (src, dst) pair; the cache is donated
         (in-place update)."""
-        paths = self._paged_paths
+        axes = self.model.paged_leaf_axes()
 
         def copy(cache, src, dst):
             def cp(p, c):
                 keys = path_keys(p)
-                if keys not in paths:
+                if keys not in axes:
                     return c
-                ax = 1 if keys[0] == "blocks" else 0
+                ax = axes[keys][0]             # the pool's page axis
                 page = jax.lax.dynamic_slice_in_dim(c, src, 1, axis=ax)
                 return jax.lax.dynamic_update_slice_in_dim(c, page, dst,
                                                            axis=ax)

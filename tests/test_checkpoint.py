@@ -59,9 +59,10 @@ def test_elastic_restore_between_meshes(tmp_path):
     """A checkpoint written under one topology restores under another —
     here 1-device meshes with different PartitionSpecs stand in for the
     256 -> 512 chip reshard (the code path is identical)."""
-    from jax.sharding import PartitionSpec as P
-    mesh_a = jax.make_mesh((1, 1), ("data", "model"))
-    mesh_b = jax.make_mesh((1,), ("data",))
+    from jax.sharding import AxisType, PartitionSpec as P
+    mesh_a = jax.make_mesh((1, 1), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
+    mesh_b = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tree = _tree()
     pspecs = {"w": P(None, None), "scale": P(None),
               "nested": {"step": P()}}

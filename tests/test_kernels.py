@@ -59,8 +59,8 @@ def test_flash_attention_matches_ref(s, t_extra, h, kv, d, window, bq, bkv):
     t = s + t_extra
     rng = np.random.default_rng(s * 100 + t)
     q = _rand(rng, (2, s, h, d), jnp.float32)
-    k = _rand(rng, (2, t, kv, d), jnp.float32)
-    v = _rand(rng, (2, t, kv, d), jnp.float32)
+    k = _rand(rng, (2, kv, t, d), jnp.float32)     # heads-major KV
+    v = _rand(rng, (2, kv, t, d), jnp.float32)
     qpos = jnp.broadcast_to(jnp.arange(s), (2, s))
     got = ops.flash_attention(q, k, v, q_positions=qpos, kv_valid_len=s,
                               window=window, bq=bq, bkv=bkv, interpret=True)
@@ -72,8 +72,8 @@ def test_flash_attention_matches_ref(s, t_extra, h, kv, d, window, bq, bkv):
 def test_flash_attention_decode_offset():
     rng = np.random.default_rng(3)
     q = _rand(rng, (2, 1, 4, 16), jnp.float32)
-    k = _rand(rng, (2, 32, 2, 16), jnp.float32)
-    v = _rand(rng, (2, 32, 2, 16), jnp.float32)
+    k = _rand(rng, (2, 2, 32, 16), jnp.float32)     # heads-major KV
+    v = _rand(rng, (2, 2, 32, 16), jnp.float32)
     got = ops.flash_attention(q, k, v, q_positions=jnp.full((2, 1), 20),
                               kv_valid_len=21, window=8, bq=8, bkv=8,
                               interpret=True)

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.dist import sharding as shd
 from repro.launch import hlo_stats
@@ -13,7 +13,8 @@ from repro.models.params import ParamSpec
 @pytest.fixture(scope="module")
 def mesh():
     # single-device mesh with named axes of size 1 keeps tests runnable
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _mesh_16_16():

@@ -1,6 +1,7 @@
 """End-to-end behaviour tests for the full system."""
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -71,6 +72,24 @@ def test_dryrun_single_cell_subprocess(tmp_path):
     assert rec["status"] == "ok", rec
     assert rec["n_devices"] == 256
     assert rec["cost"].get("flops", 0) > 0
+
+
+@pytest.mark.parametrize("alone", [False, True], ids=["cpu", "script-alone"])
+def test_chip_smoke_refuses_without_tpu_or_repo(tmp_path, alone):
+    """chip_smoke.py has no CPU path: on the CPU, or copied away from the
+    repository, it exits non-zero with a one-line reason and prints no
+    result line."""
+    script = os.path.join(os.path.dirname(SRC), "chip_smoke.py")
+    if alone:
+        script = shutil.copy(script, tmp_path)
+    res = subprocess.run([sys.executable, script],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         cwd=tmp_path, capture_output=True, text=True,
+                         timeout=300)
+    assert res.returncode != 0
+    assert '"ok"' not in res.stdout
+    reason = "no repro package" if alone else "no TPU"
+    assert reason in res.stderr.strip().splitlines()[-1]
 
 
 def test_lm_profiles_flops_sane():

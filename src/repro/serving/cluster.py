@@ -67,6 +67,9 @@ from repro.serving.engine import ServingEngine, Request
 from repro.serving.request import synth_prompts
 from repro.serving.runtime import Workload, plan_demand
 from repro.serving.slo import AdmissionController, DeadlineBook, pick_quantum
+from repro.serving.spans import (
+    RUNTIME_ADMIT, RUNTIME_COUNTERS, RUNTIME_PICK, RUNTIME_PLAN,
+    RUNTIME_SENSE, RUNTIME_TICK, span)
 from repro.serving.tenants import cluster_plans
 
 
@@ -223,9 +226,6 @@ class ClusterRuntime:
         self.deferred = 0                # admissions delayed by it
         self.tenant_shed = {t.name: 0 for t in self.tenants}
         self.tenant_deferred = {t.name: 0 for t in self.tenants}
-        self.sched_trace: list[tuple] = []  # (tenant, "prefill", rid,
-                                            #  tier, t) |
-                                            # (tenant, "decode", (rids...), t)
         self.outputs: dict[int, list[int]] = {}   # rid -> served tokens
         self.compile_time_s = 0.0        # wall time inside level switches
         self.partition_trace: list[dict[str, int]] = []
@@ -311,15 +311,16 @@ class ClusterRuntime:
         """One scheduling quantum decision for ``tenant``: counters ->
         proxy -> layer-block plan -> pool grant + engine code version."""
         st = self._state[tenant.name]
-        sample = read_counters(self.hw, idx, demands, now, self._rng,
-                               source=self.counter_source,
-                               bank=tenant.engine.counter_bank)
-        self.counter_sources[sample.source] += 1
-        if self.refit_proxy:
-            target = (sample.truth if sample.truth is not None
-                      else tenant.engine.counter_bank.pressure())
-            if target is not None:
-                self.policy.observe_counters(sample, target)
+        with span(RUNTIME_COUNTERS, tenant=tenant.name):
+            sample = read_counters(self.hw, idx, demands, now, self._rng,
+                                   source=self.counter_source,
+                                   bank=tenant.engine.counter_bank)
+            self.counter_sources[sample.source] += 1
+            if self.refit_proxy:
+                target = (sample.truth if sample.truth is not None
+                          else tenant.engine.counter_bank.pressure())
+                if target is not None:
+                    self.policy.observe_counters(sample, target)
         itf = self.policy.interference_from_counters(sample)
         task = self._task(idx, tenant)
         plan = self.policy.plan_chunk_at(task, active_tasks, itf, now,
@@ -451,198 +452,202 @@ class ClusterRuntime:
                               for t in self.tenants):
             if self.ticks >= self.max_steps:
                 break
-            while arrivals and arrivals[0][0] <= now:
-                at, name, rid = arrivals.popleft()
-                self.book.register(rid, name, tier_of(name), at,
-                                   by_name[name].plan.qos_s)
-                self._state[name].pending.append((at, rid))
-            for t in self.tenants:
-                admit(t)
+            with span(RUNTIME_TICK, tick=self.ticks) as tick_span:
+                while arrivals and arrivals[0][0] <= now:
+                    at, name, rid = arrivals.popleft()
+                    self.book.register(rid, name, tier_of(name), at,
+                                       by_name[name].plan.qos_s)
+                    self._state[name].pending.append((at, rid))
+                with span(RUNTIME_ADMIT) as admit_span:
+                    n_meta = len(meta)
+                    for t in self.tenants:
+                        admit(t)
+                    admit_span.set_metadata(
+                        admitted=len(meta) - n_meta,
+                        pending=sum(len(self._state[t.name].pending)
+                                    for t in self.tenants))
 
-            active = [t for t in self.tenants if t.engine.active_slots]
-            if not active:
-                if arrivals:                 # idle: jump to next arrival
-                    now = max(now, arrivals[0][0])
-                    continue
-                break
+                active = [t for t in self.tenants if t.engine.active_slots]
+                tick_span.set_metadata(active=len(active))
+                if not active:
+                    if arrivals:                 # idle: jump to next arrival
+                        now = max(now, arrivals[0][0])
+                        continue
+                    break
 
-            # grants of engines that drained their slots go back first
-            for t in self.tenants:
-                if not t.engine.active_slots:
-                    self._release(self._state[t.name])
+                # grants of engines that drained their slots go back first
+                for t in self.tenants:
+                    if not t.engine.active_slots:
+                        self._release(self._state[t.name])
 
-            # stamp each engine's live co-runner occupancy so its measured
-            # counter bank records who it shared the machine with
-            total_active = sum(t.engine.active_slots for t in self.tenants)
-            for t in self.tenants:
-                t.engine.co_runner_load = total_active - t.engine.active_slots
+                # stamp each engine's live co-runner occupancy so its measured
+                # counter bank records who it shared the machine with
+                total_active = sum(t.engine.active_slots for t in self.tenants)
+                for t in self.tenants:
+                    t.engine.co_runner_load = (total_active
+                                               - t.engine.active_slots)
 
-            t_tick = time.perf_counter()
-            demands = self._live_demands(meta, now)
-            active_tasks = [self._task(i, t)
-                            for i, t in enumerate(self.tenants)
-                            if t.engine.active_slots]
-            need = [task for task in active_tasks
-                    if self._state[task.tenant].grant == 0]
-            if self.scheduler == "slo" and tiered:
-                # tiered serve: grants go out in earliest-deadline order
-                # (the engine whose tightest query has least slack plans
-                # first, so it gets units before the pool runs dry)
-                ordered = sorted(
-                    need, key=lambda task: (tenant_deadline(task.tenant),
-                                            task.arrival, task.tid))
-            else:
-                ordered = self.policy.order_pending(need, now)
-            for task in ordered:
-                self._replan(task.tid, self.tenants[task.tid],
-                             active_tasks, demands, now)
+                t_tick = time.perf_counter()
+                with span(RUNTIME_SENSE):
+                    demands = self._live_demands(meta, now)
+                active_tasks = [self._task(i, t)
+                                for i, t in enumerate(self.tenants)
+                                if t.engine.active_slots]
+                need = [task for task in active_tasks
+                        if self._state[task.tenant].grant == 0]
+                if self.scheduler == "slo" and tiered:
+                    # tiered serve: grants go out in earliest-deadline order
+                    # (the engine whose tightest query has least slack plans
+                    # first, so it gets units before the pool runs dry)
+                    ordered = sorted(
+                        need, key=lambda task: (tenant_deadline(task.tenant),
+                                                task.arrival, task.tid))
+                else:
+                    ordered = self.policy.order_pending(need, now)
+                for task in ordered:
+                    with span(RUNTIME_PLAN, tenant=task.tenant):
+                        self._replan(task.tid, self.tenants[task.tid],
+                                     active_tasks, demands, now)
 
-            self.partition_trace.append(
-                {t.name: self._state[t.name].grant for t in self.tenants})
+                self.partition_trace.append(
+                    {t.name: self._state[t.name].grant for t in self.tenants})
 
-            # dispatch phase: issue every granted engine's quantum BEFORE
-            # blocking on any of them — in fused mode begin_quantum returns
-            # without a host sync, so N co-located engines' device work
-            # overlaps instead of serializing through the Python loop
-            granted = [t for t in active if self._state[t.name].grant > 0]
-            # lockstep tick quantum: every granted engine dispatches the
-            # same number of steps this tick (the smallest outstanding
-            # quantum), so no co-runner loses virtual time waiting for a
-            # longer quantum to drain — engines with bigger blocks keep
-            # their grant and continue next tick
-            q_tick = min((self._state[t.name].quantum_left
-                          for t in granted), default=0)
-            launched: list[tuple] = []
-            for t in active:
-                st = self._state[t.name]
-                if st.grant == 0:
-                    # stalled this tick (pool exhausted / exclusive quantum
-                    # pending); time still advances below, so the next tick
-                    # re-plans instead of spinning
-                    continue
-                # per-engine prefill/decode pick.  FIFO: strict
-                # alternation — an engine with a prompt mid-prefill
-                # spends every other quantum (or every quantum, if
-                # nothing is decodable) on one prefill chunk, so
-                # admissions are metered without starving its decodes.
-                # SLO: earliest-deadline pick over the engine's prefill
-                # queue and decode backlog (TTFT-urgent chunks preempt).
-                pf_slot = None
-                k_dispatch = q_tick
-                if self.scheduler == "slo":
-                    pick = pick_quantum(t.engine, self.book, now,
-                                        self.step_dt, max(q_tick, 1))
-                    do_prefill = pick is not None and pick[0] == "prefill"
+                # dispatch phase: launch every granted engine's quantum BEFORE
+                # blocking on any of them — in fused mode begin_quantum
+                # returns without a host sync, so N co-located engines'
+                # device work overlaps instead of serializing through the
+                # Python loop
+                granted = [t for t in active if self._state[t.name].grant > 0]
+                # lockstep tick quantum: every granted engine dispatches the
+                # same number of steps this tick (the smallest outstanding
+                # quantum), so no co-runner loses virtual time waiting for a
+                # longer quantum to drain — engines with bigger blocks keep
+                # their grant and continue next tick
+                q_tick = min((self._state[t.name].quantum_left
+                              for t in granted), default=0)
+                launched: list[tuple] = []
+                for t in active:
+                    st = self._state[t.name]
+                    if st.grant == 0:
+                        # stalled this tick (pool exhausted / exclusive quantum
+                        # pending); time still advances below, so the next tick
+                        # re-plans instead of spinning
+                        continue
+                    # per-engine prefill/decode pick.  FIFO: strict
+                    # alternation — an engine with a prompt mid-prefill
+                    # spends every other quantum (or every quantum, if
+                    # nothing is decodable) on one prefill chunk, so
+                    # admissions are metered without starving its decodes.
+                    # SLO: earliest-deadline pick over the engine's prefill
+                    # queue and decode backlog (TTFT-urgent chunks preempt).
+                    pf_slot = None
+                    k_dispatch = q_tick
+                    if self.scheduler == "slo":
+                        with span(RUNTIME_PICK, tenant=t.name):
+                            pick = pick_quantum(t.engine, self.book, now,
+                                                self.step_dt, max(q_tick, 1))
+                        do_prefill = pick is not None and pick[0] == "prefill"
+                        if do_prefill:
+                            pf_slot = pick[1]
+                        elif pick is not None:
+                            k_dispatch = min(q_tick, pick[1]) or 1
+                    else:
+                        do_prefill = t.engine.should_prefill(st.prefill_last)
+                        st.prefill_last = do_prefill
                     if do_prefill:
-                        pf_slot = pick[1]
-                    elif pick is not None:
-                        k_dispatch = min(q_tick, pick[1]) or 1
-                else:
-                    do_prefill = t.engine.should_prefill(st.prefill_last)
-                    st.prefill_last = do_prefill
-                if do_prefill:
-                    occupancy = 1.0 / t.engine.slots   # the prefilling row
-                    pf = t.engine.prefill_step(pf_slot)
-                    st.prefill_quanta += 1
+                        occupancy = 1.0 / t.engine.slots   # the prefilling row
+                        pf = t.engine.prefill_step(pf_slot)
+                        st.prefill_quanta += 1
+                        launched.append((t, st, None, occupancy, pf))
+                        continue
+                    # decode occupancy: slots still mid-prefill are skipped by
+                    # the decode quantum and must not be charged as busy
+                    occupancy = (t.engine.active_slots
+                                 - t.engine.prefill_pending) / t.engine.slots
+                    handle = (t.engine.begin_quantum(k_dispatch)
+                              if self.fused else None)
+                    launched.append((t, st, handle, occupancy, None))
+
+                # collect phase: one host sync per engine per quantum
+                finished: list[tuple[str, Request, int]] = []
+                prefill_done: list[tuple[_TenantState, int]] = []
+                held: list[tuple] = []
+                max_run = 1
+                for t, st, handle, occupancy, pf in launched:
                     if pf is not None:
-                        e = self.book.get(pf.rid)
-                        self.sched_trace.append(
-                            (t.name, "prefill", pf.rid,
-                             e.tier if e is not None else None, now))
-                    launched.append((t, st, None, occupancy, pf))
-                    continue
-                # decode occupancy: slots still mid-prefill are skipped by
-                # the decode quantum and must not be charged as busy
-                occupancy = (t.engine.active_slots
-                             - t.engine.prefill_pending) / t.engine.slots
-                handle = (t.engine.begin_quantum(k_dispatch)
-                          if self.fused else None)
-                if handle is not None:
-                    self.sched_trace.append((t.name, "decode", tuple(
-                        t.engine.slot_req[i].rid for i in handle.active),
-                        now))
-                launched.append((t, st, handle, occupancy, None))
+                        fin = []
+                        steps = 1
+                        row_steps = {}
+                        row_tokens = 1.0          # the one row being prefilled
+                        if pf.finished:
+                            prefill_done.append((st, pf.rid))
+                    elif self.fused:
+                        fin = t.engine.finish_quantum(handle)
+                        steps = handle.steps if handle is not None else 1
+                        row_steps = (handle.row_steps if handle is not None
+                                     else {})
+                        row_tokens = (float(handle.n_left.sum())
+                                      if handle is not None else 0.0)
+                    else:
+                        fin = t.engine.step()
+                        steps = 1
+                        row_steps = {}
+                        row_tokens = occupancy * t.engine.slots
+                    max_run = max(max_run, steps)
+                    held.append((st, st.grant, occupancy, steps, row_tokens,
+                                 t.engine.slots))
+                    for req in fin:
+                        # row_steps is in tokens; a speculative quantum emits
+                        # several per sync, so the finish offset is capped at
+                        # the quantum's clock steps
+                        finished.append((t.name, req,
+                                         min(row_steps.get(req.rid, steps),
+                                             steps)))
+                    st.quantum_left -= steps
+                    if st.quantum_left <= 0 or not t.engine.active_slots:
+                        self._release(st)
 
-            # collect phase: one host sync per engine per quantum
-            finished: list[tuple[str, Request, int]] = []
-            prefill_done: list[tuple[_TenantState, int]] = []
-            held: list[tuple] = []
-            max_run = 1
-            for t, st, handle, occupancy, pf in launched:
-                if pf is not None:
-                    fin = []
-                    steps = 1
-                    row_steps = {}
-                    row_tokens = 1.0          # the one row being prefilled
-                    if pf.finished:
-                        prefill_done.append((st, pf.rid))
-                elif self.fused:
-                    fin = t.engine.finish_quantum(handle)
-                    steps = handle.steps if handle is not None else 1
-                    row_steps = (handle.row_steps if handle is not None
-                                 else {})
-                    row_tokens = (float(handle.n_left.sum())
-                                  if handle is not None else 0.0)
-                else:
-                    fin = t.engine.step()
-                    steps = 1
-                    row_steps = {}
-                    row_tokens = occupancy * t.engine.slots
-                max_run = max(max_run, steps)
-                held.append((st, st.grant, occupancy, steps, row_tokens,
-                             t.engine.slots))
-                for req in fin:
-                    # row_steps is in tokens; a speculative quantum emits
-                    # several per sync, so the finish offset is capped at
-                    # the quantum's clock steps
-                    finished.append((t.name, req,
-                                     min(row_steps.get(req.rid, steps),
-                                         steps)))
-                st.quantum_left -= steps
-                if st.quantum_left <= 0 or not t.engine.active_slots:
-                    self._release(st)
-
-            dt = (time.perf_counter() - t_tick) if self.wall_clock \
-                else self.step_dt * max_run
-            self.ticks += 1
-            t_begin = now
-            now += dt
-            # unit-time accounting uses the same dt basis as the clock, so
-            # summarize()'s avg_units/efficiency stay consistent in both
-            # virtual and wall_clock modes.  In virtual mode an engine is
-            # charged for the steps it actually executed; busy counts the
-            # rows that actually decoded (grant * step_dt * row-steps /
-            # slots reduces to the old grant * dt * occupancy at steps=1)
-            for st, grant, occupancy, steps, row_tokens, slots in held:
-                if self.wall_clock:
-                    st.busy += grant * dt * occupancy
-                    st.alloc += grant * dt
-                else:
-                    st.busy += grant * self.step_dt * row_tokens / slots
-                    st.alloc += grant * self.step_dt * steps
-            for st, rid in prefill_done:
-                st.ttft[rid] = now - meta[rid][1]
-            for name, req, off in finished:
-                _, at, _ = meta[req.rid]
-                st = self._state[name]
-                fin = now if self.wall_clock else t_begin + off * self.step_dt
-                entry = self.book.get(req.rid)
-                has_tier = tier_of(name) is not None
-                st.records.append(QueryRecord(
-                    tenant=name, arrival=at, finish=fin,
-                    qos_s=by_name[name].plan.qos_s,
-                    ttft_s=st.ttft.get(req.rid),
-                    tier=(entry.tier if has_tier and entry is not None
-                          else "standard"),
-                    deadline=(entry.deadline
-                              if has_tier and entry is not None else None)))
-                self.outputs[req.rid] = list(req.output)
-                self.book.drop(req.rid)
+                dt = (time.perf_counter() - t_tick) if self.wall_clock \
+                    else self.step_dt * max_run
+                self.ticks += 1
+                t_begin = now
+                now += dt
+                # unit-time accounting uses the same dt basis as the clock, so
+                # summarize()'s avg_units/efficiency stay consistent in both
+                # virtual and wall_clock modes.  In virtual mode an engine is
+                # charged for the steps it actually executed; busy counts the
+                # rows that actually decoded (grant * step_dt * row-steps /
+                # slots reduces to the old grant * dt * occupancy at steps=1)
+                for st, grant, occupancy, steps, row_tokens, slots in held:
+                    if self.wall_clock:
+                        st.busy += grant * dt * occupancy
+                        st.alloc += grant * dt
+                    else:
+                        st.busy += grant * self.step_dt * row_tokens / slots
+                        st.alloc += grant * self.step_dt * steps
+                for st, rid in prefill_done:
+                    st.ttft[rid] = now - meta[rid][1]
+                for name, req, off in finished:
+                    _, at, _ = meta[req.rid]
+                    st = self._state[name]
+                    fin = (now if self.wall_clock
+                           else t_begin + off * self.step_dt)
+                    entry = self.book.get(req.rid)
+                    tiered_entry = (tier_of(name) is not None
+                                    and entry is not None)
+                    st.records.append(QueryRecord(
+                        tenant=name, arrival=at, finish=fin,
+                        qos_s=by_name[name].plan.qos_s,
+                        ttft_s=st.ttft.get(req.rid),
+                        tier=entry.tier if tiered_entry else "standard",
+                        deadline=entry.deadline if tiered_entry else None))
+                    self.outputs[req.rid] = list(req.output)
+                    self.book.drop(req.rid)
 
         for t in self.tenants:               # return whatever is still held
             self._release(self._state[t.name])
 
-        span = max((wl.arrivals[-1][0] if wl.arrivals else 0.0), 1e-9)
+        arrival_span = max((wl.arrivals[-1][0] if wl.arrivals else 0.0), 1e-9)
         per_tenant = {}
         all_records: list[QueryRecord] = []
         busy = alloc = 0.0
@@ -652,7 +657,7 @@ class ClusterRuntime:
             n_t = sum(1 for _, name in wl.arrivals if name == t.name)
             eng = t.engine
             per_tenant[t.name] = summarize(
-                st.records, n_t / span,
+                st.records, n_t / arrival_span,
                 self.tenant_conflicts[t.name] / max(n_t, 1),
                 st.busy, st.alloc,
                 shed=self.tenant_shed[t.name],

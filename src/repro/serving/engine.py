@@ -51,6 +51,11 @@ from repro.kernels import dispatch
 from repro.models.model import Model, build_model, cache_batch_axis, path_keys
 from repro.serving.paging import TRASH_PAGE, PagePool
 from repro.serving.speculative import NgramDrafter
+from repro.serving.spans import (
+    ENGINE_ADMIT, ENGINE_BEGIN_QUANTUM, ENGINE_FINISH_QUANTUM,
+    ENGINE_PREFILL_STEP, ENGINE_SET_LEVEL, PREFILL_DISPATCH,
+    PREFILL_ROW_WRITE, PREFILL_SYNC, QUANTUM_BOOKKEEP, QUANTUM_DISPATCH,
+    QUANTUM_SYNC, span)
 from repro.serving.version_cache import VersionCache
 
 # Fused-quantum executable sizes: a quantum of k decode steps runs as the
@@ -399,11 +404,13 @@ class ServingEngine:
         Returns the installed override dict (observability / tests)."""
         itf = cm.Interference.from_level(level)
         tiles = self._tiles_for(itf)
-        if tiles != self._active_tiles:
-            dispatch.install_tile_overrides(tiles)
-            self._use_version(tiles)
-            self._active_tiles = tiles
-            self.level_switches += 1
+        switched = tiles != self._active_tiles
+        with span(ENGINE_SET_LEVEL, switched=int(switched)):
+            if switched:
+                dispatch.install_tile_overrides(tiles)
+                self._use_version(tiles)
+                self._active_tiles = tiles
+                self.level_switches += 1
         self.interference_level = itf.level
         return {op: dict(kw) for op, kw in tiles.items()}
 
@@ -994,52 +1001,53 @@ class ServingEngine:
 
         With ``chunked_prefill=False`` the whole prompt prefills here,
         monolithically and per-exact-length (the reference path)."""
-        n = len(req.prompt)
-        if n < 1 or n >= self.max_len:
-            self.rejected_invalid += 1
-            raise ValueError(
-                f"prompt length {n} outside [1, {self.max_len - 1}]: the "
-                f"cache row holds max_len={self.max_len} positions and "
-                "needs at least one free for decode")
-        slot = self._free_slot()
-        if slot is None:
-            return False
-        start, row = 0, self._empty_row
-        if self.paged:
-            admitted = self._paged_admit(req, slot, n)
-            if admitted is None:
-                return False     # pool cannot commit (counted as conflict)
-            start, row = admitted
-        self.slot_req[slot] = req
-        self.slot_pos[slot] = n
-        if self.chunked_prefill:
-            self._prefill[slot] = _PrefillState(
-                req=req, row_cache=row,
-                schedule=self._prefill_schedule(n, start), done=start)
+        with span(ENGINE_ADMIT, model=self.cfg.name, rid=req.rid):
+            n = len(req.prompt)
+            if n < 1 or n >= self.max_len:
+                self.rejected_invalid += 1
+                raise ValueError(
+                    f"prompt length {n} outside [1, {self.max_len - 1}]: the "
+                    f"cache row holds max_len={self.max_len} positions and "
+                    "needs at least one free for decode")
+            slot = self._free_slot()
+            if slot is None:
+                return False
+            start, row = 0, self._empty_row
+            if self.paged:
+                admitted = self._paged_admit(req, slot, n)
+                if admitted is None:
+                    return False     # pool cannot commit (counted as conflict)
+                start, row = admitted
+            self.slot_req[slot] = req
+            self.slot_pos[slot] = n
+            if self.chunked_prefill:
+                self._prefill[slot] = _PrefillState(
+                    req=req, row_cache=row,
+                    schedule=self._prefill_schedule(n, start), done=start)
+                self._note_occupancy()
+                if drain:
+                    while not req.output:
+                        self.prefill_step()
+                return True
+            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            logits, row_cache = self._prefill_one(self.params, toks,
+                                                  self._empty_row)
+            if self.paged:
+                self.cache = self._paged_row_writer(
+                    self.cache, row_cache, jnp.int32(slot),
+                    jnp.asarray(self._write_table(slot)))
+                self._publish_slot_pages(slot, req)
+            else:
+                self.cache = self._row_writer(self.cache, row_cache,
+                                              jnp.int32(slot))
+            # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per (monolithic) admission: the prompt's first sampled token
+            first = int(jnp.argmax(logits[0]))   # prompt's first sampled token
+            self.host_syncs += 1
+            self.tokens_decoded += 1
+            self.prefill_tokens += n
             self._note_occupancy()
-            if drain:
-                while not req.output:
-                    self.prefill_step()
+            req.output.append(first)
             return True
-        toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        logits, row_cache = self._prefill_one(self.params, toks,
-                                              self._empty_row)
-        if self.paged:
-            self.cache = self._paged_row_writer(
-                self.cache, row_cache, jnp.int32(slot),
-                jnp.asarray(self._write_table(slot)))
-            self._publish_slot_pages(slot, req)
-        else:
-            self.cache = self._row_writer(self.cache, row_cache,
-                                          jnp.int32(slot))
-        # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per (monolithic) admission: the prompt's first sampled token
-        first = int(jnp.argmax(logits[0]))      # prompt's first sampled token
-        self.host_syncs += 1
-        self.tokens_decoded += 1
-        self.prefill_tokens += n
-        self._note_occupancy()
-        req.output.append(first)
-        return True
 
     @property
     def prefill_pending(self) -> int:
@@ -1095,54 +1103,60 @@ class ServingEngine:
         ran, or None when nothing is prefilling."""
         if not self._prefill:
             return None
-        if slot is None:
-            slot, st = next(iter(self._prefill.items()))
-        else:
-            st = self._prefill[slot]
-        c = st.schedule.popleft()
-        n = len(st.req.prompt)
-        valid = min(c, n - st.done)
-        toks = np.zeros(c, np.int32)
-        toks[:valid] = st.req.prompt[st.done:st.done + valid]
-        traces0 = self.version_cache.traces
-        t0 = time.perf_counter()
-        logits, st.row_cache = self._prefill_chunk(
-            self.params, jnp.asarray(toks)[None], st.row_cache,
-            jnp.int32(st.done), jnp.int32(valid))
-        st.done += valid
-        self.prefill_chunks += 1
-        self.prefill_tokens += valid
-        self.prefill_pad_tokens += c - valid
-        finished = not st.schedule
-        if finished:
-            if self.paged:
-                self.cache = self._paged_row_writer(
-                    self.cache, st.row_cache, jnp.int32(slot),
-                    jnp.asarray(self._write_table(slot)))
-                self._publish_slot_pages(slot, st.req)
+        with span(ENGINE_PREFILL_STEP, model=self.cfg.name):
+            if slot is None:
+                slot, st = next(iter(self._prefill.items()))
             else:
-                self.cache = self._row_writer(self.cache, st.row_cache,
-                                              jnp.int32(slot))
-            # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per admission (finishing chunk only)
-            first = int(jnp.argmax(logits[0]))   # the ONE sync per admission
-            # only the finishing chunk syncs, so only it yields a usable
-            # wall time (intermediate chunks are async dispatches whose
-            # device work this sync may still be draining — keying the
-            # observation by the full prompt's pow2 bucket keeps walls
-            # comparable); the trace guard drops first-visit compiles
-            # like the decode path
-            if traces0 == self.version_cache.traces:
-                self.counter_bank.observe(
-                    "prefill", _next_pow2(max(st.done, 1)),
-                    self._entry.key, time.perf_counter() - t0,
-                    tokens=valid, co_runners=self.co_runner_load)
-            self.host_syncs += 1
-            self.tokens_decoded += 1
-            st.req.output.append(first)
-            del self._prefill[slot]
-        self._note_occupancy()
-        return PrefillQuantum(slot=slot, rid=st.req.rid, chunk=c,
-                              tokens=valid, finished=finished)
+                st = self._prefill[slot]
+            c = st.schedule.popleft()
+            n = len(st.req.prompt)
+            valid = min(c, n - st.done)
+            toks = np.zeros(c, np.int32)
+            toks[:valid] = st.req.prompt[st.done:st.done + valid]
+            finished = not st.schedule
+            traces0 = self.version_cache.traces
+            t0 = time.perf_counter()
+            with span(PREFILL_DISPATCH, model=self.cfg.name, rid=st.req.rid,
+                      start=st.done, chunk=c, tokens=valid,
+                      finished=int(finished)):
+                logits, st.row_cache = self._prefill_chunk(
+                    self.params, jnp.asarray(toks)[None], st.row_cache,
+                    jnp.int32(st.done), jnp.int32(valid))
+            st.done += valid
+            self.prefill_chunks += 1
+            self.prefill_tokens += valid
+            self.prefill_pad_tokens += c - valid
+            if finished:
+                with span(PREFILL_ROW_WRITE, rid=st.req.rid):
+                    if self.paged:
+                        self.cache = self._paged_row_writer(
+                            self.cache, st.row_cache, jnp.int32(slot),
+                            jnp.asarray(self._write_table(slot)))
+                        self._publish_slot_pages(slot, st.req)
+                    else:
+                        self.cache = self._row_writer(self.cache, st.row_cache,
+                                                      jnp.int32(slot))
+                with span(PREFILL_SYNC, rid=st.req.rid):
+                    # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per admission (finishing chunk only)
+                    first = int(jnp.argmax(logits[0]))
+                # only the finishing chunk syncs, so only it yields a usable
+                # wall time (intermediate chunks are async dispatches whose
+                # device work this sync may still be draining — keying the
+                # observation by the full prompt's pow2 bucket keeps walls
+                # comparable); the trace guard drops first-visit compiles
+                # like the decode path
+                if traces0 == self.version_cache.traces:
+                    self.counter_bank.observe(
+                        "prefill", _next_pow2(max(st.done, 1)),
+                        self._entry.key, time.perf_counter() - t0,
+                        tokens=valid, co_runners=self.co_runner_load)
+                self.host_syncs += 1
+                self.tokens_decoded += 1
+                st.req.output.append(first)
+                del self._prefill[slot]
+            self._note_occupancy()
+            return PrefillQuantum(slot=slot, rid=st.req.rid, chunk=c,
+                                  tokens=valid, finished=finished)
 
     def add_request(self, req: Request) -> bool:
         """Deprecated alias for ``admit_request(req, drain=True)``.
@@ -1191,66 +1205,76 @@ class ServingEngine:
                   if r is not None and i not in self._prefill]
         if not active or k <= 0:
             return None
-        n_left = np.zeros(self.slots, np.int32)
-        toks = np.zeros(self.slots, np.int32)
-        for i in active:
-            req = self.slot_req[i]
-            need = req.max_new_tokens + 1 - len(req.output)
-            room = self.max_len - 1 - int(self.slot_pos[i])
-            # a live row always decodes at least one step — exactly what
-            # sequential step() does before its finish check, and it keeps
-            # degenerate admissions (max_new_tokens=0, prompt at the length
-            # limit) finishing instead of spinning with a zero budget
-            n_left[i] = max(1, min(need, room))
-            toks[i] = req.output[-1]
-        if fused and self._spec_enabled:
-            handle = self._try_spec_quantum(int(k), active, n_left.copy(),
-                                            toks)
-            if handle is not None:
-                return handle
-            # no usable draft / no room for the d+1 write span: the plain
-            # fused quantum below is the per-row fallback
-        if self.paged:
-            cap = (1 if not fused else
-                   min(int(k), self.quantum_buckets[-1]))
-            n_left = self._paged_preflight(active,
-                                           np.minimum(n_left, cap))
-            if not any(n_left[i] > 0 for i in active):
-                return None      # every decodable row waits on a free page
-        if not fused:
-            # per-slot positions: each row decodes at its own absolute
-            # position and attends under its own kv-valid horizon, so
-            # mixed-length / staggered prompts stay exact (free slots
-            # compute garbage rows that the next admission's pristine-row
-            # prefill replaces)
+        k = int(k)
+        with span(ENGINE_BEGIN_QUANTUM, model=self.cfg.name):
+            n_left = np.zeros(self.slots, np.int32)
+            toks = np.zeros(self.slots, np.int32)
+            for i in active:
+                req = self.slot_req[i]
+                need = req.max_new_tokens + 1 - len(req.output)
+                room = self.max_len - 1 - int(self.slot_pos[i])
+                # a live row always decodes at least one step — exactly what
+                # sequential step() does before its finish check, and it keeps
+                # degenerate admissions (max_new_tokens=0, prompt at the length
+                # limit) finishing instead of spinning with a zero budget
+                n_left[i] = max(1, min(need, room))
+                toks[i] = req.output[-1]
+            if fused and self._spec_enabled:
+                handle = self._try_spec_quantum(k, active, n_left.copy(), toks)
+                if handle is not None:
+                    return handle
+                # no usable draft / no room for the d+1 write span: the plain
+                # fused quantum below is the per-row fallback
+            if self.paged:
+                cap = (1 if not fused else min(k, self.quantum_buckets[-1]))
+                n_left = self._paged_preflight(active,
+                                               np.minimum(n_left, cap))
+                if not any(n_left[i] > 0 for i in active):
+                    return None      # every decodable row waits on a free page
+            if not fused:
+                # per-slot positions: each row decodes at its own absolute
+                # position and attends under its own kv-valid horizon, so
+                # mixed-length / staggered prompts stay exact (free slots
+                # compute garbage rows that the next admission's pristine-row
+                # prefill replaces)
+                n_left = np.minimum(n_left, 1)
+                traces0 = self.version_cache.traces
+                t0 = time.perf_counter()
+                with self._dispatch_span(active, k, 1, 1, n_left, "decode"):
+                    logits, self.cache = self._decode(
+                        self.params, {"tokens": jnp.asarray(toks)}, self.cache,
+                        jnp.asarray(self.slot_pos))
+                return QuantumHandle(block=jnp.argmax(logits, axis=-1)[None],
+                                     n_left=n_left, steps=1, active=active,
+                                     t0=t0, traces0=traces0, bucket=1,
+                                     tiles=self._entry.key)
+            steps = int(min(k, int(n_left.max()), self.quantum_buckets[-1]))
+            bucket = next(b for b in self.quantum_buckets if b >= steps)
+            n_left = np.minimum(n_left, steps)
+            qfn = self.version_cache.quantum(self._entry, bucket, self.params,
+                                             self.cache, self.slots)
+            # timestamp AFTER the executable lookup: a cold K-bucket's AOT
+            # compile is host-side cost the runtimes charge, not device work
+            # the measured counters may attribute to interference
             traces0 = self.version_cache.traces
             t0 = time.perf_counter()
-            logits, self.cache = self._decode(
-                self.params, {"tokens": jnp.asarray(toks)}, self.cache,
-                jnp.asarray(self.slot_pos))
-            n_left = np.minimum(n_left, 1)
-            return QuantumHandle(block=jnp.argmax(logits, axis=-1)[None],
-                                 n_left=n_left, steps=1, active=active,
-                                 t0=t0, traces0=traces0, bucket=1,
-                                 tiles=self._entry.key)
-        steps = int(min(int(k), int(n_left.max()),
-                        self.quantum_buckets[-1]))
-        bucket = next(b for b in self.quantum_buckets if b >= steps)
-        n_left = np.minimum(n_left, steps)
-        qfn = self.version_cache.quantum(self._entry, bucket, self.params,
-                                         self.cache, self.slots)
-        # timestamp AFTER the executable lookup: a cold K-bucket's AOT
-        # compile is host-side cost the runtimes charge, not device work
-        # the measured counters may attribute to interference
-        traces0 = self.version_cache.traces
-        t0 = time.perf_counter()
-        block, self.cache, _ = qfn(
-            self.params, jnp.asarray(toks), self.cache,
-            jnp.asarray(self.slot_pos), jnp.asarray(n_left))
-        self.quantum_calls += 1
-        return QuantumHandle(block=block, n_left=n_left, steps=steps,
-                             active=active, t0=t0, traces0=traces0,
-                             bucket=bucket, tiles=self._entry.key)
+            with self._dispatch_span(active, k, steps, bucket, n_left,
+                                     "decode"):
+                block, self.cache, _ = qfn(
+                    self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(self.slot_pos), jnp.asarray(n_left))
+            self.quantum_calls += 1
+            return QuantumHandle(block=block, n_left=n_left, steps=steps,
+                                 active=active, t0=t0, traces0=traces0,
+                                 bucket=bucket, tiles=self._entry.key)
+
+    def _dispatch_span(self, active: list[int], asked: int, steps: int,
+                       bucket: int, n_left: np.ndarray, kind: str):
+        """The ``engine.quantum.dispatch`` span of one decode call."""
+        return span(QUANTUM_DISPATCH, model=self.cfg.name, slots=self.slots,
+                    rows=len(active), prefilling=len(self._prefill),
+                    asked=asked, steps=steps, bucket=bucket,
+                    tokens=int(n_left.sum()), kind=kind)
 
     def _try_spec_quantum(self, k: int, active: list[int],
                           n_left: np.ndarray,
@@ -1307,9 +1331,10 @@ class ServingEngine:
             self._entry, bucket, d, self.params, self.cache, self.slots)
         traces0 = self.version_cache.traces
         t0 = time.perf_counter()
-        block, n_emit, accepted, self.cache, _ = sfn(
-            self.params, jnp.asarray(toks), jnp.asarray(drafts),
-            self.cache, jnp.asarray(self.slot_pos), jnp.asarray(n_left))
+        with self._dispatch_span(active, k, cap, bucket, n_left, "spec"):
+            block, n_emit, accepted, self.cache, _ = sfn(
+                self.params, jnp.asarray(toks), jnp.asarray(drafts),
+                self.cache, jnp.asarray(self.slot_pos), jnp.asarray(n_left))
         self.quantum_calls += 1
         self.spec_quanta += 1
         self.tokens_drafted += d * len(active)
@@ -1330,24 +1355,36 @@ class ServingEngine:
         land in ``handle.row_steps``."""
         if handle is None:
             return []
+        with span(ENGINE_FINISH_QUANTUM, model=self.cfg.name):
+            with span(QUANTUM_SYNC):
+                if handle.kind == "spec":
+                    # ONE fused sync for the whole spec quantum: token
+                    # block plus per-row emission/acceptance come back in
+                    # a single device->host transfer instead of three
+                    # veltair: ignore[host-sync-in-hot-path] THE sanctioned per-quantum sync (spec path: fused triple)
+                    block, emitted, accepted = jax.device_get(
+                        (handle.block, handle.emitted, handle.accepted))
+                else:
+                    # veltair: ignore[host-sync-in-hot-path] THE sanctioned per-quantum sync (one block transfer per quantum)
+                    block = np.asarray(handle.block)
+                    emitted = accepted = None
+            with span(QUANTUM_BOOKKEEP):
+                return self._bookkeep_quantum(handle, block, emitted,
+                                              accepted)
+
+    def _bookkeep_quantum(self, handle: QuantumHandle, block, emitted,
+                          accepted) -> list[Request]:
+        """Everything ``finish_quantum`` does after its sync, on host
+        values: counters, the measured-counter observation, each row's
+        tokens and positions, and freeing finished slots."""
+        self.host_syncs += 1
         if handle.kind == "spec":
-            # ONE fused sync for the whole spec quantum: token block plus
-            # per-row emission/acceptance come back in a single
-            # device->host transfer instead of three serialized ones
-            # veltair: ignore[host-sync-in-hot-path] THE sanctioned per-quantum sync (spec path: fused triple)
-            block, emitted, accepted = jax.device_get(
-                (handle.block, handle.emitted, handle.accepted))
             block = np.asarray(block)
             emitted = np.asarray(emitted).astype(np.int32)
             accepted = np.asarray(accepted)
             # fold the actual per-row emission into n_left so every
             # consumer below (and in the runtimes) sees real token counts
             handle.n_left = emitted
-        else:
-            # veltair: ignore[host-sync-in-hot-path] THE sanctioned per-quantum sync (one block transfer per quantum, PR 4)
-            block = np.asarray(handle.block)
-        self.host_syncs += 1
-        if handle.kind == "spec":
             d = handle.drafted
             for i in handle.active:
                 self.tokens_accepted += max(int(emitted[i]) - 1, 0)

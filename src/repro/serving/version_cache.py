@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispatch
+from repro.serving.spans import VCACHE_BUILD, span
 
 
 def tiles_key(tiles: dict[str, dict]) -> tuple:
@@ -178,17 +179,20 @@ class VersionCache:
         # this entry's numerics — and the trace counter stays honest.
         def prefill(params, tokens, row_cache):
             self.traces += 1
-            with dispatch.tile_context(snap):
+            with span(VCACHE_BUILD, kind="prefill", bucket=tokens.shape[1]), \
+                    dispatch.tile_context(snap):
                 return model.prefill(params, {"tokens": tokens}, row_cache)
 
         def decode(params, inputs, cache, t):
             self.traces += 1
-            with dispatch.tile_context(snap):
+            with span(VCACHE_BUILD, kind="decode", bucket=1), \
+                    dispatch.tile_context(snap):
                 return model.decode_step(params, inputs, cache, t)
 
         def prefill_chunk(params, tokens, row_cache, t0, valid_len):
             self.traces += 1
-            with dispatch.tile_context(snap):
+            with span(VCACHE_BUILD, kind="prefill_chunk",
+                      bucket=tokens.shape[1]), dispatch.tile_context(snap):
                 return model.prefill_chunk(params, {"tokens": tokens},
                                            row_cache, t0, valid_len)
 
@@ -234,8 +238,9 @@ class VersionCache:
         vec = jax.ShapeDtypeStruct((int(batch),), jnp.int32)
         cache_sds = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), cache)
-        fn = (jax.jit(qfn, donate_argnums=(2,))
-              .lower(params, vec, cache_sds, vec, vec).compile())
+        with span(VCACHE_BUILD, kind="quantum", bucket=k):
+            fn = (jax.jit(qfn, donate_argnums=(2,))
+                  .lower(params, vec, cache_sds, vec, vec).compile())
         entry.quanta[k] = fn
         return fn
 
@@ -274,7 +279,8 @@ class VersionCache:
         mat = jax.ShapeDtypeStruct((int(batch), d), jnp.int32)
         cache_sds = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), cache)
-        fn = (jax.jit(sfn, donate_argnums=(3,))
-              .lower(params, vec, mat, cache_sds, vec, vec).compile())
+        with span(VCACHE_BUILD, kind="spec", bucket=k):
+            fn = (jax.jit(sfn, donate_argnums=(3,))
+                  .lower(params, vec, mat, cache_sds, vec, vec).compile())
         entry.spec[(k, d)] = fn
         return fn

@@ -648,25 +648,31 @@ class Model:
 
     def select_cache_rows(self, live: jax.Array, new_cache: PyTree,
                           old_cache: PyTree) -> PyTree:
-        """Per-row cache select: rows where ``live`` is True take
-        ``new_cache``, frozen rows keep ``old_cache`` bit-exact.  This is
-        what lets a fused multi-step decode freeze finished slots: a
-        frozen row's recurrent state (SSM/RG-LRU) and KV writes are fully
-        reverted, so its cache is indistinguishable from one that was
-        never stepped.
+        """Per-row cache select: what lets a fused multi-step decode
+        freeze finished slots.  Rows where ``live`` is True take
+        ``new_cache``; for a frozen row the contract follows each leaf's
+        kind, read from its cache spec:
 
-        Page-pool leaves have no per-row batch axis and are kept as
-        written: a frozen row replays the *same* KV write at its frozen
-        (token, position) — its own pages and dense state are bit-exact
-        reverted, so the recomputation is idempotent — and a free row's
-        table maps every entry to the pinned trash page."""
-        paged = (self.paged_leaf_paths()
-                 if isinstance(new_cache, dict) and "page_table" in new_cache
-                 else frozenset())
+        - leaves without a ``"seq"`` axis (SSM conv/state, RG-LRU state,
+          the local-window ring's ``k``/``v``/``pos``) are reverted to
+          ``old_cache`` bit-exact: a step would overwrite state the row
+          still reads;
+        - linear KV leaves (:meth:`paged_leaf_paths`: attention k/v, MLA
+          c_kv/k_rope, dense rows or page pools) and the page table pass
+          through as written.  A frozen row is bit-exact on its visible
+          positions ``< pos`` and holds a replayed write at ``pos`` —
+          the same (token, position) every frozen step, with its other
+          state reverted — which the attention mask hides and the row's
+          next live step overwrites before any read.  A free row's write
+          lands in a row the next admission refills whole (dense) or on
+          the pinned trash page (paged).
+
+        So no pass over the whole KV cache runs per step."""
+        linear = self.paged_leaf_paths()
 
         def sel(path, n, o):
             keys = path_keys(path)
-            if keys == ("page_table",) or keys in paged:
+            if keys == ("page_table",) or keys in linear:
                 return n
             shape = [1] * n.ndim
             shape[cache_batch_axis(path)] = live.shape[0]
@@ -682,9 +688,10 @@ class Model:
 
         Args: ``tokens`` (B,) int32 last-sampled token per row; ``pos``
         (B,) int32 absolute positions; ``n_left`` (B,) int32 per-row step
-        budget (rows stop advancing after their budget: token, position
-        and cache all freeze, so mid-quantum completions and slots
-        shorter than the quantum stay exact).  ``k`` is static — the
+        budget (rows stop advancing after their budget: token and
+        position freeze, and the cache as :meth:`select_cache_rows`
+        states, so mid-quantum completions and slots shorter than the
+        quantum stay exact).  ``k`` is static — the
         serving layer compiles one executable per K-bucket.
 
         Returns ``(block (k, B) int32, cache, pos)``; column ``i`` of

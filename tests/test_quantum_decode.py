@@ -7,6 +7,10 @@ path and Pallas interpret mode.  The quantum boundary is also the host
 boundary: exactly ONE device->host sync per fused call, and a full level
 sweep after ``warmup()`` leaves the version-cache trace counter flat
 with the fused quantum entries already present."""
+import dataclasses
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +19,8 @@ import pytest
 from repro.configs import get_reduced_config
 from repro.core import cost_model as cm
 from repro.kernels import dispatch
+from repro.models import build_model
+from repro.models.model import cache_batch_axis, path_keys
 from repro.serving.engine import Request, ServingEngine
 
 MAX_LEN = 32
@@ -38,16 +44,33 @@ PROMPT_LENS = (3, 7, 2)          # deliberately misaligned
 MAX_NEW = (6, 3, 5)              # rows complete at different quantum steps
 
 
-@pytest.fixture(scope="module")
-def setup():
-    from repro.models import build_model
-    cfg = get_reduced_config("gemma-2b")
+def _reduced(name):
+    """The reduced config of ``name``; recurrentgemma's local-attention
+    window is cut below MAX_LEN so its ring cache wraps (overwrites
+    entries the row still reads) inside a test's sequences."""
+    cfg = get_reduced_config(name)
+    if cfg.rglru is not None:
+        cfg = dataclasses.replace(
+            cfg, rglru=dataclasses.replace(cfg.rglru, window_size=8))
+    return cfg
+
+
+@functools.cache
+def _family(name):
+    """A reduced model of ``name`` with its params and the shared
+    prompts."""
+    cfg = _reduced(name)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(13)
     prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
                for n in PROMPT_LENS]
     return cfg, model, params, prompts
+
+
+@pytest.fixture(scope="module")
+def setup():
+    return _family("gemma-2b")
 
 
 @pytest.fixture(autouse=True)
@@ -71,35 +94,53 @@ SCHEDULE = [(2, 0.0, True), (3, 1.0, True), (4, 0.3, False),
 
 
 def _run_schedule(cfg, params, prompts, *, fused):
+    """-> (engine, requests, frozen): ``frozen`` counts the fused quanta
+    in which some active row's budget fell short of the executed bucket,
+    so that row froze on device."""
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                            quantum_buckets=(2, 4))
     reqs = _make_reqs(prompts)
     pending = list(reqs)
+    frozen = 0
     for k, level, admit in SCHEDULE:
         if admit and pending:
             if engine.admit_request(pending[0], drain=True):
                 pending.pop(0)
         engine.set_interference_level(level)
         if fused:
-            engine.step_quantum(k)
+            h = engine.begin_quantum(k)
+            if h is not None:
+                frozen += any(h.n_left[i] < h.bucket for i in h.active)
+            engine.finish_quantum(h)
         else:
             for _ in range(k):
                 engine.step()
         if not pending and all(r.done for r in reqs):
             break
     assert all(r.done for r in reqs), "schedule must drain every request"
-    return engine, reqs
+    return engine, reqs, frozen
 
 
-@pytest.mark.parametrize("mode", ["xla", "interpret"])
-def test_quantum_identical_to_sequential_steps(setup, mode):
-    cfg, _, params, prompts = setup
+# One case per cache kind: gemma-2b (MQA linear KV, both dispatch modes),
+# starcoder2-3b (GQA linear KV), mamba2-780m (SSM conv/state),
+# recurrentgemma-2b (RG-LRU state + wrapping local-window ring).
+@pytest.mark.parametrize("arch,mode", [
+    pytest.param("gemma-2b", "xla", id="xla"),
+    pytest.param("gemma-2b", "interpret", id="interpret"),
+    pytest.param("starcoder2-3b", "xla", id="starcoder2-3b-xla"),
+    pytest.param("mamba2-780m", "xla", id="mamba2-780m-xla"),
+    pytest.param("recurrentgemma-2b", "xla", id="recurrentgemma-2b-xla"),
+])
+def test_quantum_identical_to_sequential_steps(arch, mode):
+    cfg, _, params, prompts = _family(arch)
     dispatch.set_mode(mode)
-    _, want = _run_schedule(cfg, params, prompts, fused=False)
-    eng, got = _run_schedule(cfg, params, prompts, fused=True)
+    _, want, _ = _run_schedule(cfg, params, prompts, fused=False)
+    eng, got, frozen = _run_schedule(cfg, params, prompts, fused=True)
     for w, g in zip(want, got):
-        assert g.output == w.output, (mode, g.rid, g.output, w.output)
-    # the fused run really coarsened the dispatch unit
+        assert g.output == w.output, (arch, mode, g.rid, g.output, w.output)
+    # rows froze inside a quantum, and the fused run really coarsened the
+    # dispatch unit
+    assert frozen >= 1
     assert eng.quantum_calls >= 3
     assert eng.tokens_per_sync > 1.0
 
@@ -287,3 +328,95 @@ def test_admission_write_is_jitted_and_row_local(setup):
     assert len(done) == len(reqs)
     for i, r in enumerate(reqs):
         assert r.output[:4] == want[i][:4]
+
+
+def _rows(cache, row):
+    """{key path: leaf restricted to batch row ``row``} of a dense cache."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    return {path_keys(p): np.asarray(jnp.take(leaf, row,
+                                              axis=cache_batch_axis(p)))
+            for p, leaf in flat}
+
+
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "mamba2-780m",
+                                  "recurrentgemma-2b",
+                                  "deepseek-v2-lite-16b"])
+def test_frozen_row_cache_contract(arch):
+    """One fused quantum of K=4 steps over three rows: row 0 is frozen
+    for the whole quantum, row 1 freezes after 2 steps, row 2 runs all 4.
+    Frozen rows keep recurrent and ring leaves bit-exact whole, and linear
+    KV leaves bit-exact on every visible position (< pos); the live work
+    equals per-step decode of each row's own budget."""
+    cfg = _reduced(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    b, plen, k = 3, 6, 4
+    prompts = jax.random.randint(jax.random.PRNGKey(2), (b, plen), 0,
+                                 cfg.vocab_size, jnp.int32)
+    logits, cache0 = jax.jit(model.prefill)(
+        params, {"tokens": prompts}, model.init_cache(b, MAX_LEN))
+    toks0 = jnp.argmax(logits, -1).astype(jnp.int32)
+    pos0 = jnp.full((b,), plen, jnp.int32)
+    n_left = np.array([0, 2, k], np.int32)
+
+    qfn = jax.jit(model.decode_quantum, static_argnums=(5,))
+    block, cache_q, pos_q = qfn(params, toks0, cache0, pos0,
+                                jnp.asarray(n_left), k)
+    np.testing.assert_array_equal(np.asarray(pos_q), plen + n_left)
+
+    # per-step decode: after[s] is the whole batch stepped s times
+    step = jax.jit(model.decode_step)
+    after, toks, pos = [cache0], toks0, pos0
+    for i in range(k):
+        lg, c = step(params, {"tokens": toks}, after[-1], pos)
+        toks = jnp.argmax(lg, -1).astype(jnp.int32)
+        pos = pos + 1
+        after.append(c)
+        assert int(block[i, 2]) == int(toks[2])
+
+    axes = model.paged_leaf_axes()
+    for row, n in enumerate(n_left.tolist()):
+        got, want = _rows(cache_q, row), _rows(after[n], row)
+        vis = plen + n                         # visible positions < vis
+        replay = _rows(after[n + 1], row) if n < k else None
+        for key, g in got.items():
+            if key not in axes:                # recurrent / ring: whole
+                np.testing.assert_array_equal(
+                    g, want[key], err_msg=f"{arch} row {row} {key}")
+                continue
+            seq = axes[key][1] - 1             # the row axis is dropped
+            np.testing.assert_array_equal(
+                g.take(range(vis), seq), want[key].take(range(vis), seq),
+                err_msg=f"{arch} row {row} {key} visible positions")
+            if replay is not None:     # frozen: the replayed write is
+                np.testing.assert_array_equal(   # the next live step's
+                    g.take(vis, seq), replay[key].take(vis, seq),
+                    err_msg=f"{arch} row {row} {key} replayed write")
+
+
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "mamba2-780m",
+                                  "recurrentgemma-2b",
+                                  "deepseek-v2-lite-16b"])
+def test_quantum_selects_only_state_leaves(arch):
+    """Structural guard on the compiled decode quantum: no ``select``
+    produces a whole stacked linear KV leaf (a per-step pass over the
+    entire KV cache), while every recurrent / ring leaf keeps its
+    frozen-row select.  Batch and length are picked so no leaf's shape
+    coincides with another's."""
+    from repro.models.params import abstract_params
+    model = build_model(_reduced(arch))
+    b, t = 5, 48
+    cache = abstract_params(model.cache_specs(b, t))
+    rows = jax.ShapeDtypeStruct((b,), jnp.int32)
+    hlo = jax.jit(model.decode_quantum, static_argnums=(5,)).lower(
+        abstract_params(model.param_specs()), rows, cache, rows, rows,
+        4).compile().as_text()
+    selected = {tuple(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"= \w+\[([\d,]*)\]\S* select\(", hlo)}
+    linear = model.paged_leaf_paths()
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    for path, leaf in flat:
+        if path_keys(path) in linear:
+            assert leaf.shape not in selected, (arch, path_keys(path))
+        else:
+            assert leaf.shape in selected, (arch, path_keys(path))

@@ -224,22 +224,15 @@ def arctic_train(variants):
 
 
 def deepseek_decode(variants):
+    """Decode takes the absorbed MLA form (chosen from its one-token
+    shape); ``seqshard`` also shards the latent cache's positions."""
     arch, shp = "deepseek-v2-lite-16b", "decode_32k"
-    from repro.configs import get_config
     from repro.dist import sharding as shd
-    cfg = get_config(arch)
     if "baseline" in variants:
-        measure_variant(arch, shp, label="baseline(plain-mla)")
-    if "absorb" in variants:
-        cfg2 = dataclasses.replace(
-            cfg, mla=dataclasses.replace(cfg.mla, absorb=True))
-        measure_variant(arch, shp, cfg=cfg2, label="mla-absorb")
-    if "absorb-seqshard" in variants:
-        cfg2 = dataclasses.replace(
-            cfg, mla=dataclasses.replace(cfg.mla, absorb=True))
+        measure_variant(arch, shp, label="baseline(absorbed-mla)")
+    if "seqshard" in variants:
         rules = shd.make_rules("serve", False, seq_parallel=True)
-        measure_variant(arch, shp, cfg=cfg2, rules=rules,
-                        label="mla-absorb+seqshard")
+        measure_variant(arch, shp, rules=rules, label="mla-absorb+seqshard")
 
 
 CELLS = {"gemma-decode": gemma_decode, "arctic-train": arctic_train,

@@ -113,7 +113,7 @@ def prefill(model, params, entry, toks, chunk: int = 16):
     import numpy as np
     row = model.init_cache(1, MAX_LEN)
     for s in range(0, len(toks), chunk):
-        logits, row = entry.prefill_chunk(
+        logits, row, _ = entry.prefill_chunk(
             params, jnp.asarray(toks[s:s + chunk])[None], row,
             jnp.int32(s), jnp.int32(chunk))
     return np.asarray(logits[0], np.float32), row

@@ -10,7 +10,7 @@ import dataclasses
 
 from repro.configs.base import (MLAConfig, ModelConfig, MoEConfig,
                                 RGLRUConfig, ShapeConfig, SHAPES, SSMConfig,
-                                shape_applicable)
+                                YarnConfig, shape_applicable)
 
 from repro.configs import (arctic_480b, deepseek_v2_lite_16b, gemma_2b,
                            llama3_405b, mamba2_780m, minicpm_2b,
@@ -89,6 +89,7 @@ def get_reduced_config(name: str) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig", "RGLRUConfig",
+    "YarnConfig",
     "ShapeConfig", "SHAPES", "ARCH_NAMES", "get_config", "get_shape",
     "get_reduced_config", "all_cells", "shape_applicable",
 ]

@@ -21,7 +21,7 @@ CONFIG = ModelConfig(
         top_k=2,
         expert_d_ff=4864,
         dense_residual=True,
-        capacity_factor=1.25,
+        norm_topk_prob=True,        # top-2 gates renormalised (Mixtral-style)
     ),
     activation="swiglu",
     norm_type="rmsnorm",

@@ -13,16 +13,40 @@ from typing import Literal
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts.  The router always scores all ``num_experts`` (its
+    published width); this chip computes the part of the layer that its
+    ``held_experts`` routed experts, ``first_held_expert`` onwards, give
+    (the chip's share of an expert-parallel deployment; None holds all)."""
     num_experts: int
     top_k: int
     expert_d_ff: int
     num_shared_experts: int = 0
     shared_d_ff: int = 0
-    capacity_factor: float = 1.25
-    router_jitter: float = 0.0
+    # gates of the top-k picks divided by their sum (arctic), or the
+    # softmax probabilities as they are (deepseek-v2: norm_topk_prob false)
+    norm_topk_prob: bool = True
+    held_experts: int | None = None
+    first_held_expert: int = 0
     # arctic: dense FFN residual branch running in parallel with the MoE branch
     dense_residual: bool = False
     aux_loss_weight: float = 0.01
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held_experts is None \
+            else self.held_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling``
+    with ``type: yarn``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,9 +56,7 @@ class MLAConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     q_lora_rank: int | None = None   # v2-lite: no q compression
-    # decode-time matrix absorption (W_uk folded into q, W_uv into W_o).
-    # Beyond-paper optimization; see EXPERIMENTS.md §Perf.
-    absorb: bool = False
+    yarn: YarnConfig | None = None
 
 
 @dataclasses.dataclass(frozen=True)

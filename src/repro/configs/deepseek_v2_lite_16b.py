@@ -1,10 +1,14 @@
 """DeepSeek-V2-Lite — 16B MoE with Multi-head Latent Attention.
 
-[arXiv:2405.04434; hf]  27L d_model=2048 16H d_ff=1408(expert), vocab=102400.
-MLA kv_lora_rank=512; 2 shared + 64 routed experts, top-6.  Layer 0 uses a
-dense FFN (d_ff=10944) like the released checkpoint.
+[arXiv:2405.04434; hf deepseek-ai/DeepSeek-V2-Lite config.json]  27L
+d_model=2048 16H, vocab=102400 (untied).  MLA kv_lora_rank=512, no q
+compression, YaRN rope scaling (factor 40 over 4096 positions).  Layer 0
+is a dense SwiGLU FFN (d_ff=10944); layers 1-26 route each token to 6 of
+64 experts (d_ff=1408) by a softmax router whose top-6 gates are used as
+they are (``norm_topk_prob`` false, ``routed_scaling_factor`` 1), plus 2
+shared experts.
 """
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, YarnConfig
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -22,7 +26,7 @@ CONFIG = ModelConfig(
         expert_d_ff=1408,
         num_shared_experts=2,
         shared_d_ff=2816,            # 2 shared experts fused: 2 x 1408
-        capacity_factor=1.25,
+        norm_topk_prob=False,
     ),
     mla=MLAConfig(
         kv_lora_rank=512,
@@ -30,6 +34,9 @@ CONFIG = ModelConfig(
         qk_rope_head_dim=64,
         v_head_dim=128,
         q_lora_rank=None,
+        yarn=YarnConfig(factor=40.0, original_max_position_embeddings=4096,
+                        beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                        mscale_all_dim=0.707),
     ),
     first_dense_layers=1,
     first_dense_d_ff=10944,

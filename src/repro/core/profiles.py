@@ -41,10 +41,11 @@ def _layer_flops(cfg: ModelConfig, tokens: int, kv_len: int,
     if kind == "moe_arctic":
         moe = cfg.moe
         fl += tokens * 3 * m * cfg.d_ff * 2                       # dense res
-        fl += tokens * moe.top_k * 3 * m * moe.expert_d_ff * 2    # routed
+        fl += tokens * moe.top_k * 3 * m * moe.expert_d_ff * 2 \
+            * moe.n_held / moe.num_experts                        # routed
         fl += tokens * m * moe.num_experts * 2                    # router
         wb += 3 * m * cfg.d_ff * IT
-        wb += moe.num_experts * 3 * m * moe.expert_d_ff * IT
+        wb += moe.n_held * 3 * m * moe.expert_d_ff * IT
     if kind == "moe_ds":
         mla, moe = cfg.mla, cfg.moe
         h = cfg.num_heads
@@ -56,10 +57,11 @@ def _layer_flops(cfg: ModelConfig, tokens: int, kv_len: int,
         fl += tokens * proj * 2
         wb += proj * IT
         fl += 2 * 2 * tokens * kv_len * h * (dn + dr + dv) / 2
-        fl += tokens * moe.top_k * 3 * m * moe.expert_d_ff * 2
+        fl += tokens * moe.top_k * 3 * m * moe.expert_d_ff * 2 \
+            * moe.n_held / moe.num_experts          # picks on held experts
         fl += tokens * 3 * m * moe.shared_d_ff * 2
         fl += tokens * m * moe.num_experts * 2
-        wb += moe.num_experts * 3 * m * moe.expert_d_ff * IT
+        wb += moe.n_held * 3 * m * moe.expert_d_ff * IT
     if kind == "ssm":
         s = cfg.ssm
         d_in = 2 * s.d_inner + 2 * s.num_groups * s.state_dim + s.num_heads

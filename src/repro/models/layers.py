@@ -58,11 +58,15 @@ def apply_norm(params: dict, x: jax.Array, norm_type: str = "rmsnorm",
 # Rotary embeddings (RoPE and qwen2-vl M-RoPE)
 # --------------------------------------------------------------------------
 def _rope_angles(positions: jax.Array, head_dim: int, theta: float,
-                 mrope_sections: tuple[int, int, int] | None) -> jax.Array:
+                 mrope_sections: tuple[int, int, int] | None,
+                 inv_freq=None) -> jax.Array:
     """-> (B, S, D/2) fp32 angles."""
     half = head_dim // 2
-    freq_idx = jnp.arange(half, dtype=jnp.float32)
-    inv_freq = theta ** (-2.0 * freq_idx / head_dim)   # (half,)
+    if inv_freq is None:
+        freq_idx = jnp.arange(half, dtype=jnp.float32)
+        inv_freq = theta ** (-2.0 * freq_idx / head_dim)   # (half,)
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     if mrope_sections is None:
         pos = positions.astype(jnp.float32)            # (B, S)
         return pos[..., None] * inv_freq               # (B, S, half)
@@ -81,10 +85,13 @@ def _rope_angles(positions: jax.Array, head_dim: int, theta: float,
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               mrope_sections: tuple[int, int, int] | None = None) -> jax.Array:
-    """x: (B, S, H, D). Split-halves convention (llama / gemma)."""
+               mrope_sections: tuple[int, int, int] | None = None, *,
+               inv_freq=None) -> jax.Array:
+    """x: (B, S, H, D). Split-halves convention (llama / gemma).
+    ``inv_freq`` (D/2,) replaces ``theta ** (-2i / D)`` (scaled rope)."""
     d = x.shape[-1]
-    ang = _rope_angles(positions, d, theta, mrope_sections)  # (B,S,half)
+    ang = _rope_angles(positions, d, theta, mrope_sections,
+                       inv_freq)                              # (B,S,half)
     sin = jnp.sin(ang)[:, :, None, :]
     cos = jnp.cos(ang)[:, :, None, :]
     xf = x.astype(jnp.float32)

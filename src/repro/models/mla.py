@@ -2,24 +2,47 @@
 
 KV is compressed to a ``kv_lora_rank`` latent (plus a single shared RoPE key
 head), which is what gets cached: 512+64 dims/token instead of
-2*H*head_dim.  Two decode paths:
+2*H*head_dim.  The form follows the call's shape:
 
-  * plain    — cached latents are re-expanded through W_uk/W_uv each step
-               (faithful to the algebra, heavy at long context)
-  * absorbed — W_uk is folded into the query and W_uv into the output
-               projection, so attention runs directly in latent space.
-               O(H*T*(lora+rope)) instead of O(T*lora*H*(dn+dv)) per step.
-               This is a beyond-paper decode optimization (EXPERIMENTS.md
-               §Perf, deepseek decode_32k hillclimb).
+  * expanded (prefill chunks, speculative verify: several query tokens)
+               — cached latents are re-expanded through W_uk/W_uv into
+               per-head keys and values, as the published model computes;
+  * absorbed (decode steps and quanta: one query token per row) — W_uk is
+               folded into the query and W_uv applied after attention, so
+               attention reads only the 576-wide latents:
+               O(H*T*(lora+rope)) per step instead of O(T*lora*H*(dn+dv)).
+
+Rope follows the config: plain ``rope_theta``, or YaRN
+(:func:`yarn_inv_freq`), which also scales the softmax
+(:func:`softmax_scale`).  The rope columns use the rotate-half layout of
+``layers.apply_rope``; the published checkpoint stores them interleaved
+and permutes them before rotating, which for drawn weights is a
+permutation of the ``wq``/``w_dkv`` rope columns.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import MLAConfig, ModelConfig
+from repro.configs.base import MLAConfig, ModelConfig, YarnConfig
 from repro.models.layers import NEG_INF, apply_norm, apply_rope, norm_specs
 from repro.models.params import ParamSpec
+
+
+# lanes of a TPU memory tile: the cached latent's width is rounded up to it
+LANES = 128
+
+
+def latent_width(mla: MLAConfig) -> int:
+    """Width of a cached position: ``c_kv`` (r) and the rope key (dr),
+    then zeros up to a multiple of LANES.  At a width the lanes do not
+    divide (576) the TPU compiler lays the cache out position-minor as a
+    program argument and width-minor inside the decode loop, and copies
+    the whole cache between the two on the way in and out."""
+    return -(-(mla.kv_lora_rank + mla.qk_rope_head_dim) // LANES) * LANES
 
 
 def mla_specs(cfg: ModelConfig, mla: MLAConfig) -> dict:
@@ -36,22 +59,69 @@ def mla_specs(cfg: ModelConfig, mla: MLAConfig) -> dict:
     }
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn: YarnConfig) -> np.ndarray:
+    """Rotary inverse frequencies (dim // 2,) under YaRN, as DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: pair ``i``'s
+    frequency ``f_i = theta ** (-2i / dim)`` becomes
+    ``f_i / factor * ramp_i + f_i * (1 - ramp_i)``, with ``ramp`` rising
+    linearly from 0 at ``floor(corr(beta_fast))`` to 1 at
+    ``ceil(corr(beta_slow))``, where ``corr(r) = dim * ln(L / (2 pi r)) /
+    (2 ln theta)`` and ``L`` is ``original_max_position_embeddings``."""
+    def corr(rot):
+        return dim * math.log(yarn.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return freq / yarn.factor * ramp + freq * (1.0 - ramp)
+
+
+def softmax_scale(mla: MLAConfig) -> float:
+    """``(dn + dr) ** -0.5``, times ``mscale(factor, mscale_all_dim) ** 2``
+    under YaRN."""
+    scale = (mla.qk_nope_head_dim + mla.qk_rope_head_dim) ** -0.5
+    if mla.yarn is not None and mla.yarn.mscale_all_dim:
+        scale *= yarn_mscale(mla.yarn.factor, mla.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(x, positions, cfg: ModelConfig, mla: MLAConfig):
+    """Rotate ``x (B,S,heads,dr)`` at ``positions`` by the config's rope."""
+    y = mla.yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    dr = mla.qk_rope_head_dim
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     inv_freq=yarn_inv_freq(dr, cfg.rope_theta, y))
+    # cos and sin scaled by mscale(mscale) / mscale(mscale_all_dim)
+    ratio = yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor,
+                                                          y.mscale_all_dim)
+    return out if ratio == 1.0 else (out.astype(jnp.float32)
+                                     * ratio).astype(out.dtype)
+
+
 def _compress(params, x, cfg: ModelConfig, mla: MLAConfig, positions):
-    """x -> (c_kv (B,S,r) normalized, k_rope (B,S,1,dr) rotated)."""
-    r, dr = mla.kv_lora_rank, mla.qk_rope_head_dim
+    """x -> (c_kv (B,S,r) normalized, k_rope (B,S,dr) rotated)."""
+    r = mla.kv_lora_rank
     ckv_full = jnp.einsum("bsm,mr->bsr", x, params["w_dkv"].astype(x.dtype))
     c_kv = apply_norm(params["kv_norm"], ckv_full[..., :r], cfg.norm_type)
-    k_rope = ckv_full[..., r:][:, :, None, :]          # (B,S,1,dr)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
-    return c_kv, k_rope
+    k_rope = ckv_full[..., r:][:, :, None, :]          # one shared head
+    return c_kv, _rope(k_rope, positions, cfg, mla)[:, :, 0]
 
 
 def _queries(params, x, cfg: ModelConfig, mla: MLAConfig, positions):
-    dn, dr = mla.qk_nope_head_dim, mla.qk_rope_head_dim
+    dn = mla.qk_nope_head_dim
     q = jnp.einsum("bsm,mhd->bshd", x, params["wq"].astype(x.dtype))
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q[..., :dn], _rope(q[..., dn:], positions, cfg, mla)
 
 
 def _mask(t: int, q_positions: jax.Array, kv_valid_len) -> jax.Array:
@@ -67,53 +137,43 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
                   cache_index: jax.Array | None = None,
                   page_table: jax.Array | None = None,
                   ) -> tuple[jax.Array, dict | None]:
-    """MLA self-attention; cache = {"c_kv": (B,T,r), "k_rope": (B,T,1,dr)}.
+    """MLA self-attention; cache = {"latent": (B,T,W)}: each position's
+    normalized latent ``c_kv`` and its rotated rope key side by side in
+    one leaf, zero-padded to ``W = latent_width(mla)`` (one layout, and
+    decode scores both halves in one product).
 
-    With ``page_table`` (B, pages_per_slot) the cached latents live in
-    physical page pools ``(n_pages + 1, page_size, ...)``: decode scatters
+    With ``page_table`` (B, pages_per_slot) the cached latents live in a
+    physical page pool ``(n_pages + 1, page_size, W)``: decode scatters
     the new latent into its slot's page and gathers the full horizon
     through the table (latents are already memory-compressed, so the
     gather reference path is the paged MLA path — no kernel variant)."""
     mla = cfg.mla
     b, s, m = x.shape
-    dn, dr, dv = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
-    scale = (dn + dr) ** -0.5
+    r, dr = mla.kv_lora_rank, mla.qk_rope_head_dim
+    scale = softmax_scale(mla)
 
     q_nope, q_rope = _queries(params, x, cfg, mla, positions)
     c_kv, k_rope = _compress(params, x, cfg, mla, positions)
+    pad = latent_width(mla) - r - dr
+    lat = jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype),
+                           jnp.zeros((b, s, pad), c_kv.dtype)], -1)
 
     if cache is None:
-        ckv_all, krope_all, kv_len = c_kv, k_rope, s
+        lat_all, kv_len = lat, s
         new_cache = None
     elif page_table is not None:
         idx = jnp.broadcast_to(
             jnp.asarray(cache_index, jnp.int32).reshape(-1), (b,))
-        ps_sz = cache["c_kv"].shape[1]
-        if s == 1:
-            bidx = jnp.arange(b, dtype=jnp.int32)
-            phys = page_table[bidx, idx // ps_sz]
-            off = idx % ps_sz
-            ckv_pool = cache["c_kv"].at[phys, off].set(
-                c_kv[:, 0].astype(cache["c_kv"].dtype))
-            krope_pool = cache["k_rope"].at[phys, off].set(
-                k_rope[:, 0].astype(cache["k_rope"].dtype))
-        else:
-            # multi-token (speculative verify): scatter each row's S new
-            # latents through the table; unmapped spans hit the trash page
-            rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            phys = page_table[bidx, rows // ps_sz]   # (B,S)
-            off = rows % ps_sz
-            ckv_pool = cache["c_kv"].at[phys, off].set(
-                c_kv.astype(cache["c_kv"].dtype))
-            krope_pool = cache["k_rope"].at[phys, off].set(
-                k_rope.astype(cache["k_rope"].dtype))
-        new_cache = {"c_kv": ckv_pool, "k_rope": krope_pool}
-        n_slot = page_table.shape[1]
-        ckv_all = ckv_pool[page_table].reshape(
-            b, n_slot * ps_sz, *ckv_pool.shape[2:])
-        krope_all = krope_pool[page_table].reshape(
-            b, n_slot * ps_sz, *krope_pool.shape[2:])
+        pool = cache["latent"]
+        ps_sz = pool.shape[1]
+        # each row's S new latents through the table (S > 1: speculative
+        # verify); unmapped spans hit the trash page
+        rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        phys = page_table[bidx, rows // ps_sz]   # (B,S)
+        pool = pool.at[phys, rows % ps_sz].set(lat.astype(pool.dtype))
+        new_cache = {"latent": pool}
+        lat_all = pool[page_table].reshape(b, -1, pool.shape[-1])
         kv_len = idx + s
     else:
         idx = jnp.asarray(cache_index, jnp.int32)
@@ -122,44 +182,45 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
             # latents at its own index, per-row kv-valid horizon
             rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
             bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            ckv_all = cache["c_kv"].at[bidx, rows].set(
-                c_kv.astype(cache["c_kv"].dtype))
-            krope_all = cache["k_rope"].at[bidx, rows].set(
-                k_rope.astype(cache["k_rope"].dtype))
+            lat_all = cache["latent"].at[bidx, rows].set(
+                lat.astype(cache["latent"].dtype))
         else:
-            ckv_all = jax.lax.dynamic_update_slice(
-                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype),
+            lat_all = jax.lax.dynamic_update_slice(
+                cache["latent"], lat.astype(cache["latent"].dtype),
                 (0, idx, 0))
-            krope_all = jax.lax.dynamic_update_slice(
-                cache["k_rope"], k_rope.astype(cache["k_rope"].dtype),
-                (0, idx, 0, 0))
         kv_len = idx + s
-        new_cache = {"c_kv": ckv_all, "k_rope": krope_all}
-    t = ckv_all.shape[1]
+        new_cache = {"latent": lat_all}
+    t = lat_all.shape[1]
     w_uk = params["w_uk"].astype(x.dtype)
     w_uv = params["w_uv"].astype(x.dtype)
 
-    if mla.absorb:
+    if s == 1:
+        # absorbed: one query per row scores the raw latents, [W_uk-folded
+        # query, rope query] against [c_kv, k_rope] in one product; the
+        # folded query and the latent context stay in float32
         def attn_chunk(q_nope_c, q_rope_c, pos_c):
-            # fold W_uk into q: q_lat (B,C,H,r); score against raw latents.
             mask = _mask(t, pos_c, kv_len)[:, None]
-            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope_c, w_uk)
-            s_nope = jnp.einsum("bshr,btr->bhst",
-                                q_lat.astype(jnp.float32),
-                                ckv_all.astype(jnp.float32))
-            s_rope = jnp.einsum("bshd,btzd->bhst",
-                                q_rope_c.astype(jnp.float32),
-                                krope_all.astype(jnp.float32))
-            scores = jnp.where(mask, (s_nope + s_rope) * scale, NEG_INF)
+            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope_c.astype(jnp.float32),
+                               w_uk.astype(jnp.float32))
+            q_cat = jnp.concatenate(
+                [q_lat, q_rope_c.astype(jnp.float32),
+                 jnp.zeros(q_lat.shape[:-1] + (pad,), jnp.float32)], -1)
+            scores = jnp.einsum("bshr,btr->bhst", q_cat,
+                                lat_all.astype(jnp.float32))
+            scores = jnp.where(mask, scores * scale, NEG_INF)
             probs = jax.nn.softmax(scores, axis=-1)
             ctx = jnp.einsum("bhst,btr->bshr", probs,
-                             ckv_all.astype(jnp.float32))   # (B,C,H,r)
-            return jnp.einsum("bshr,rhd->bshd", ctx.astype(x.dtype), w_uv)
+                             lat_all[..., :r].astype(jnp.float32))
+            return jnp.einsum("bshr,rhd->bshd", ctx,
+                              w_uv.astype(jnp.float32)).astype(x.dtype)
     else:
-        k_nope = jnp.einsum("btr,rhd->bthd", ckv_all, w_uk)   # (B,T,H,dn)
-        v = jnp.einsum("btr,rhd->bthd", ckv_all, w_uv)        # (B,T,H,dv)
+        ckv32 = lat_all[..., :r].astype(jnp.float32)
+        k_nope = jnp.einsum("btr,rhd->bthd", ckv32,
+                            w_uk.astype(jnp.float32))         # (B,T,H,dn)
+        v = jnp.einsum("btr,rhd->bthd", ckv32,
+                       w_uv.astype(jnp.float32))              # (B,T,H,dv)
         k_full = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(krope_all,
+            [k_nope, jnp.broadcast_to(lat_all[:, :, None, r:r + dr],
                                       (b, t, cfg.num_heads, dr))], axis=-1)
 
         def attn_chunk(q_nope_c, q_rope_c, pos_c):

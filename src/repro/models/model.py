@@ -95,11 +95,9 @@ def _block_specs(cfg: ModelConfig, kind: str) -> dict:
 
 def _attn_cache_specs(cfg: ModelConfig, batch: int, t_max: int) -> dict:
     if cfg.mla is not None:
-        r, dr = cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
-        return {"c_kv": ParamSpec((batch, t_max, r), jnp.bfloat16,
-                                  ("batch", "seq", "kv_lora"), init="zeros"),
-                "k_rope": ParamSpec((batch, t_max, 1, dr), jnp.bfloat16,
-                                    ("batch", "seq", None, "head_dim"),
+        w = mla_mod.latent_width(cfg.mla)
+        return {"latent": ParamSpec((batch, t_max, w), jnp.bfloat16,
+                                    ("batch", "seq", "kv_lora"),
                                     init="zeros")}
     # heads-major (B, K, T, D): the layout the Pallas attention kernels
     # read block-by-block, so decode never transposes the cache
@@ -145,24 +143,43 @@ def _block_cache_specs(cfg: ModelConfig, kind: str, batch: int,
 # --------------------------------------------------------------------------
 # Per-block application
 # --------------------------------------------------------------------------
+def _no_routing(cfg: ModelConfig) -> jax.Array | None:
+    """Routing counts before any MoE layer: zeros for a model with routed
+    experts, None (no program output at all) for one without."""
+    if cfg.moe is None:
+        return None
+    return jnp.zeros((moe_mod.N_ROUTING_COUNTS,), jnp.int32)
+
+
+def _add_routing(a: jax.Array | None, b: jax.Array | None):
+    if a is None:
+        return b
+    return a if b is None else a + b
+
+
 def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
                  positions: jax.Array, cache: dict | None,
                  t: jax.Array | int, valid_len: jax.Array | None = None,
                  page_table: jax.Array | None = None,
-                 ) -> tuple[jax.Array, dict | None, jax.Array]:
-    """-> (x, new_cache, aux_loss).
+                 mask: jax.Array | None = None,
+                 ) -> tuple[jax.Array, dict | None, jax.Array,
+                            jax.Array | None]:
+    """-> (x, new_cache, aux_loss, routing counts; None outside MoE
+    layers).
 
     ``valid_len`` (chunked-prefill padding): tokens past it must be exact
     no-ops for carried state.  Recurrent mixers and the window ring cache
     mask explicitly; linear KV caches need nothing — a padded row is
     causally invisible until decode reaches its position, and the decode
-    write at that position overwrites it first."""
-    aux = jnp.zeros((), jnp.float32)
+    write at that position overwrites it first.  ``mask`` (B,S) marks the
+    real tokens an MoE layer's routing counts cover
+    (:func:`moe.apply_moe`)."""
+    aux, routed = jnp.zeros((), jnp.float32), None
     if kind == "ssm":
         h, new_cache = ssm_mod.mamba2_block(
             params["mixer"], L.apply_norm(params["ln1"], x, cfg.norm_type),
             cfg=cfg, cache=cache, valid_len=valid_len)
-        return x + h, new_cache, aux
+        return x + h, new_cache, aux, routed
 
     if kind == "rec":
         h, new_cache = rg_mod.rglru_block(
@@ -172,7 +189,7 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
         m = L.apply_mlp(params["mlp"],
                         L.apply_norm(params["ln2"], x, cfg.norm_type),
                         cfg.activation)
-        return x + m, new_cache, aux
+        return x + m, new_cache, aux, routed
 
     # attention-bearing blocks -------------------------------------------
     xa = L.apply_norm(params["ln1"], x, cfg.norm_type)
@@ -197,15 +214,17 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
     if kind in ("dense", "ds_dense0", "attn_local"):
         x = x + L.apply_mlp(params["mlp"], xm, cfg.activation)
     elif kind == "moe_arctic":
-        moe_out, aux = moe_mod.apply_moe(params["moe"], xm, cfg, cfg.moe)
+        moe_out, aux, routed = moe_mod.apply_moe(params["moe"], xm, cfg,
+                                                 cfg.moe, mask)
         x = x + L.apply_mlp(params["mlp"], xm, cfg.activation) + moe_out
     elif kind == "moe_ds":
-        moe_out, aux = moe_mod.apply_moe(params["moe"], xm, cfg, cfg.moe)
+        moe_out, aux, routed = moe_mod.apply_moe(params["moe"], xm, cfg,
+                                                 cfg.moe, mask)
         if "shared" in params:
             moe_out = moe_out + L.apply_mlp(params["shared"], xm,
                                             cfg.activation)
         x = x + moe_out
-    return x, new_cache, aux
+    return x, new_cache, aux, routed
 
 
 def _local_attention(cfg: ModelConfig, params: dict, x: jax.Array, *,
@@ -323,7 +342,7 @@ class Model:
     def paged_leaf_paths(self) -> frozenset:
         """Key-paths of cache leaves that page: linear KV leaves, i.e.
         those whose spec carries a ``"seq"`` axis (attention k/v, MLA
-        c_kv/k_rope).  Recurrent state (SSM/RG-LRU) and the local-window
+        latent).  Recurrent state (SSM/RG-LRU) and the local-window
         ring cache are O(1)-or-O(window) per slot and stay dense."""
         return frozenset(self.paged_leaf_axes())
 
@@ -360,8 +379,8 @@ class Model:
         rows to a physical page pool: the batch axis becomes
         ``n_pages + 1`` physical pages (index 0 = pinned trash page) and
         the seq axis ``page_size`` — ``(B, K, T, D)`` attention rows give
-        ``(n_pages + 1, K, page_size, D)`` pools, ``(B, T, r)`` MLA
-        latents ``(n_pages + 1, page_size, r)``.  One logical page uses
+        ``(n_pages + 1, K, page_size, D)`` pools, ``(B, T, W)`` MLA
+        latents ``(n_pages + 1, page_size, W)``.  One logical page uses
         the same physical index in every layer's pool, so a single
         per-slot page table addresses all layers."""
         if t_max % page_size:
@@ -433,26 +452,29 @@ class Model:
 
     # -- stacks ------------------------------------------------------------
     def _run_blocks(self, params, x, *, positions, caches, t, remat="none",
-                    valid_len=None, page_table=None):
+                    valid_len=None, page_table=None, mask=None):
+        """-> (x, new caches, aux loss, routing counts summed over the
+        MoE layers (:func:`_no_routing`); ``mask`` as in
+        :func:`_apply_block`)."""
         cfg, plan = self.cfg, self.plan
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total, routed = jnp.zeros((), jnp.float32), _no_routing(cfg)
         new_caches: dict = {}
 
         def group_fn(gp, x, gcache):
-            aux_g = jnp.zeros((), jnp.float32)
+            aux_g, routed_g = jnp.zeros((), jnp.float32), _no_routing(cfg)
             ncache: dict = {}
             for j, kind in enumerate(plan.scan_kinds):
                 key = kind if len(plan.scan_kinds) == 1 else f"{kind}_{j}"
                 c = gcache.get(key) if gcache is not None else None
-                x2, nc, a = _apply_block(cfg, kind, gp[key], x,
-                                         positions=positions, cache=c, t=t,
-                                         valid_len=valid_len,
-                                         page_table=page_table)
+                x2, nc, a, r = _apply_block(cfg, kind, gp[key], x,
+                                            positions=positions, cache=c, t=t,
+                                            valid_len=valid_len,
+                                            page_table=page_table, mask=mask)
                 x = x2
-                aux_g = aux_g + a
+                aux_g, routed_g = aux_g + a, _add_routing(routed_g, r)
                 if nc is not None:
                     ncache[key] = nc
-            return x, (ncache or None), aux_g
+            return x, (ncache or None), (aux_g, routed_g)
 
         if remat == "full":
             group_fn = jax.checkpoint(group_fn)
@@ -463,11 +485,11 @@ class Model:
 
         for i, kind in enumerate(plan.prologue):
             c = caches.get(f"pro_{i}") if caches is not None else None
-            x, nc, a = _apply_block(cfg, kind, params[f"pro_{i}"], x,
-                                    positions=positions, cache=c, t=t,
-                                    valid_len=valid_len,
-                                    page_table=page_table)
-            aux_total += a
+            x, nc, a, r = _apply_block(cfg, kind, params[f"pro_{i}"], x,
+                                       positions=positions, cache=c, t=t,
+                                       valid_len=valid_len,
+                                       page_table=page_table, mask=mask)
+            aux_total, routed = aux_total + a, _add_routing(routed, r)
             if nc is not None:
                 new_caches[f"pro_{i}"] = nc
 
@@ -480,46 +502,46 @@ class Model:
                 for gi in range(plan.n_groups):
                     gp = jax.tree_util.tree_map(lambda p: p[gi],
                                                 params["blocks"])
-                    x, _, a = group_fn(gp, x, None)
-                    aux_total = aux_total + a
+                    x, _, (a, r) = group_fn(gp, x, None)
+                    aux_total, routed = aux_total + a, _add_routing(routed, r)
             elif bcaches is None:
                 def body(carry, gp):
-                    xx, aux = carry
-                    xx, _, a = group_fn(gp, xx, None)
-                    return (xx, aux + a), None
-                (x, aux_total), _ = jax.lax.scan(
-                    body, (x, aux_total), params["blocks"])
+                    xx, aux, rt = carry
+                    xx, _, (a, r) = group_fn(gp, xx, None)
+                    return (xx, aux + a, _add_routing(rt, r)), None
+                (x, aux_total, routed), _ = jax.lax.scan(
+                    body, (x, aux_total, routed), params["blocks"])
             elif L.ANALYSIS_UNROLL:
                 ncs_list = []
                 for gi in range(plan.n_groups):
                     gp = jax.tree_util.tree_map(lambda p: p[gi],
                                                 params["blocks"])
                     gc = jax.tree_util.tree_map(lambda c: c[gi], bcaches)
-                    x, nc, a = group_fn(gp, x, gc)
-                    aux_total = aux_total + a
+                    x, nc, (a, r) = group_fn(gp, x, gc)
+                    aux_total, routed = aux_total + a, _add_routing(routed, r)
                     ncs_list.append(nc)
                 new_caches["blocks"] = jax.tree_util.tree_map(
                     lambda *cs: jnp.stack(cs), *ncs_list)
             else:
                 def body(carry, xs):
-                    xx, aux = carry
+                    xx, aux, rt = carry
                     gp, gc = xs
-                    xx, nc, a = group_fn(gp, xx, gc)
-                    return (xx, aux + a), nc
-                (x, aux_total), ncs = jax.lax.scan(
-                    body, (x, aux_total), (params["blocks"], bcaches))
+                    xx, nc, (a, r) = group_fn(gp, xx, gc)
+                    return (xx, aux + a, _add_routing(rt, r)), nc
+                (x, aux_total, routed), ncs = jax.lax.scan(
+                    body, (x, aux_total, routed), (params["blocks"], bcaches))
                 new_caches["blocks"] = ncs
 
         for i, kind in enumerate(plan.epilogue):
             c = caches.get(f"epi_{i}") if caches is not None else None
-            x, nc, a = _apply_block(cfg, kind, params[f"epi_{i}"], x,
-                                    positions=positions, cache=c, t=t,
-                                    valid_len=valid_len,
-                                    page_table=page_table)
-            aux_total += a
+            x, nc, a, r = _apply_block(cfg, kind, params[f"epi_{i}"], x,
+                                       positions=positions, cache=c, t=t,
+                                       valid_len=valid_len,
+                                       page_table=page_table, mask=mask)
+            aux_total, routed = aux_total + a, _add_routing(routed, r)
             if nc is not None:
                 new_caches[f"epi_{i}"] = nc
-        return x, (new_caches or None), aux_total
+        return x, (new_caches or None), aux_total, routed
 
     # -- entry points --------------------------------------------------------
     def forward(self, params, inputs, *, positions=None, remat="none"):
@@ -533,8 +555,8 @@ class Model:
             positions = self._default_positions(b, s)
         x = self._embed_inputs(params, inputs, positions)
         x = hint(x, ("batch", "seq", "embed"))
-        x, _, aux = self._run_blocks(params, x, positions=positions,
-                                     caches=None, t=0, remat=remat)
+        x, _, aux, _ = self._run_blocks(params, x, positions=positions,
+                                        caches=None, t=0, remat=remat)
         x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
         return L.unembed(params["embed"], x, cfg), aux
 
@@ -565,8 +587,8 @@ class Model:
         if positions is None:
             positions = self._default_positions(b, s)
         x = self._embed_inputs(params, inputs, positions)
-        x, new_cache, _ = self._run_blocks(params, x, positions=positions,
-                                           caches=cache, t=0)
+        x, new_cache, _, _ = self._run_blocks(params, x, positions=positions,
+                                              caches=cache, t=0)
         x = L.apply_norm(params["final_norm"], x[:, -1:], cfg.norm_type)
         logits = L.unembed(params["embed"], x, cfg)
         return logits[:, 0], new_cache
@@ -585,14 +607,12 @@ class Model:
         overwrites it.  Chaining chunks (t0 = 0, C, 2C, ...) over a
         prompt therefore yields a cache bit-identical to one monolithic
         :meth:`prefill` — while the compiled shapes are the fixed bucket
-        set, not the prompt-length distribution.  (Exception: capacity
-        MoE routing drops tokens per routing *group*, whose size follows
-        the batch shape — so MoE families are chunk-schedule-dependent
-        whenever any token exceeds expert capacity, exactly as in any
-        chunked-prefill serving system.)
+        set, not the prompt-length distribution.
 
-        Returns (logits (B, V) at the last *valid* token, updated cache);
-        only the final chunk's logits are meaningful to sample from."""
+        Returns (logits (B, V) at the last *valid* token, updated cache,
+        routing counts of the real tokens (:func:`moe.apply_moe`, summed
+        over the MoE layers; None without MoE)); only the final chunk's
+        logits are meaningful to sample from."""
         cfg = self.cfg
         b, s = (inputs["tokens"].shape if "tokens" in inputs
                 else inputs["embeds"].shape[:2])
@@ -603,12 +623,15 @@ class Model:
         if positions is None:
             positions = self._default_positions(b, s, t0)
         x = self._embed_inputs(params, inputs, positions)
-        x, new_cache, _ = self._run_blocks(params, x, positions=positions,
-                                           caches=cache, t=t0, valid_len=vl)
+        real = None if cfg.moe is None else jnp.broadcast_to(
+            jnp.arange(s, dtype=jnp.int32)[None] < vl, (b, s))
+        x, new_cache, _, routed = self._run_blocks(
+            params, x, positions=positions, caches=cache, t=t0, valid_len=vl,
+            mask=real)
         last = jax.lax.dynamic_slice_in_dim(x, vl - 1, 1, axis=1)
         last = L.apply_norm(params["final_norm"], last, cfg.norm_type)
         logits = L.unembed(params["embed"], last, cfg)
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, routed
 
     def decode_step(self, params, inputs, cache, t):
         """One-token decode at absolute position ``t`` — a scalar int32
@@ -620,6 +643,11 @@ class Model:
         :meth:`init_paged_cache`) routes KV reads/writes through the
         per-slot page table; the table itself passes through unchanged
         (the host owns it)."""
+        return self._decode(params, inputs, cache, t)[:2]
+
+    def _decode(self, params, inputs, cache, t, live=None):
+        """:meth:`decode_step` -> (logits, cache, routing counts of the
+        rows where ``live`` (B,) is True; every row when None)."""
         cfg = self.cfg
         t = jnp.asarray(t, jnp.int32)
         page_table = cache.get("page_table") if isinstance(cache, dict) \
@@ -636,15 +664,16 @@ class Model:
             step_in = {"embeds": inputs["embeds"].reshape(b, 1, -1)}
         positions = self._default_positions(b, 1, t)
         x = self._embed_inputs(params, step_in, positions)
-        x, new_cache, _ = self._run_blocks(params, x, positions=positions,
-                                           caches=caches, t=t,
-                                           page_table=page_table)
+        x, new_cache, _, routed = self._run_blocks(
+            params, x, positions=positions, caches=caches, t=t,
+            page_table=page_table,
+            mask=None if live is None else live.reshape(b, 1))
         x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
         logits = L.unembed(params["embed"], x, cfg)
         if page_table is not None:
             new_cache = dict(new_cache)
             new_cache["page_table"] = page_table
-        return logits[:, 0], new_cache
+        return logits[:, 0], new_cache, routed
 
     def select_cache_rows(self, live: jax.Array, new_cache: PyTree,
                           old_cache: PyTree) -> PyTree:
@@ -658,7 +687,7 @@ class Model:
           ``old_cache`` bit-exact: a step would overwrite state the row
           still reads;
         - linear KV leaves (:meth:`paged_leaf_paths`: attention k/v, MLA
-          c_kv/k_rope, dense rows or page pools) and the page table pass
+          latent, dense rows or page pools) and the page table pass
           through as written.  A frozen row is bit-exact on its visible
           positions ``< pos`` and holds a replayed write at ``pos`` —
           the same (token, position) every frozen step, with its other
@@ -694,27 +723,34 @@ class Model:
         quantum stay exact).  ``k`` is static — the
         serving layer compiles one executable per K-bucket.
 
-        Returns ``(block (k, B) int32, cache, pos)``; column ``i`` of
-        ``block`` is valid for the first ``n_left[i]`` rows.
+        Returns ``(block (k, B) int32, cache, pos, routed)``; column ``i``
+        of ``block`` is valid for the first ``n_left[i]`` rows, and
+        ``routed`` sums the routing counts of the live rows' steps
+        (:func:`moe.apply_moe`; frozen rows count nothing; None without
+        MoE).
         """
         def body(carry, j):
-            toks, cache_c, pos_c = carry
-            logits, new_cache = self.decode_step(
-                params, {"tokens": toks}, cache_c, pos_c)
+            toks, cache_c, pos_c, routed = carry
+            # only routing counts read the mask: without MoE the step is
+            # decode_step's program as it is
+            logits, new_cache, r = self._decode(
+                params, {"tokens": toks}, cache_c, pos_c,
+                None if self.cfg.moe is None else j < n_left)
             live = j < n_left
             nxt = jnp.where(live,
                             jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             toks)
             new_cache = self.select_cache_rows(live, new_cache, cache_c)
             pos_c = jnp.where(live, pos_c + 1, pos_c)
-            return (nxt, new_cache, pos_c), nxt
+            return (nxt, new_cache, pos_c, _add_routing(routed, r)), nxt
 
-        (_, cache, pos), block = jax.lax.scan(
+        n_left = jnp.asarray(n_left, jnp.int32)
+        (_, cache, pos, routed), block = jax.lax.scan(
             body,
             (jnp.asarray(tokens, jnp.int32), cache,
-             jnp.asarray(pos, jnp.int32)),
+             jnp.asarray(pos, jnp.int32), _no_routing(self.cfg)),
             jnp.arange(int(k), dtype=jnp.int32))
-        return block, cache, pos
+        return block, cache, pos, routed
 
     def _has_nonseq_cache_leaves(self) -> bool:
         """True when any cache leaf carries recurrent / ring state (no
@@ -783,7 +819,7 @@ class Model:
         x = self._embed_inputs(params, {"tokens": seq}, positions)
 
         # pass 1: full-validity forward — logits at every candidate
-        x1, cache1, _ = self._run_blocks(
+        x1, cache1, _, _ = self._run_blocks(
             params, x, positions=positions, caches=caches, t=pos,
             valid_len=jnp.full((b,), s, jnp.int32), page_table=page_table)
         h = L.apply_norm(params["final_norm"], x1, cfg.norm_type)
@@ -797,7 +833,7 @@ class Model:
 
         if self._has_nonseq_cache_leaves():
             # restore pass: exact recurrent/ring state after n_emit tokens
-            _, cache2, _ = self._run_blocks(
+            _, cache2, _, _ = self._run_blocks(
                 params, x, positions=positions, caches=caches, t=pos,
                 valid_len=n_emit, page_table=page_table)
             seq_paths = self.paged_leaf_paths()

@@ -122,6 +122,9 @@ class _PrefillState:
     row_cache: object              # accumulating batch-1 row cache
     schedule: collections.deque    # remaining chunk sizes (bucket table)
     done: int = 0                  # real prompt tokens prefilled so far
+    # on-device routing counts of the chunks run so far (MoE models), read
+    # back with the finishing chunk's sync
+    routed: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -166,6 +169,9 @@ class QuantumHandle:
     emitted: jax.Array | None = None    # (B,) device n_emit (spec only)
     accepted: jax.Array | None = None   # (B,) device acceptance (spec only)
     drafted: int = 0               # draft depth dispatched (spec only)
+    # on-device routing counts of a fused quantum (MoE models), read back
+    # with its block in the one sync
+    routed: jax.Array | None = None
 
 
 class ServingEngine:
@@ -300,6 +306,12 @@ class ServingEngine:
         self.host_syncs = 0
         self.tokens_decoded = 0
         self.quantum_calls = 0
+        # MoE routing over the real tokens of chunked prefill and fused
+        # decode quanta, read back with the syncs those already make:
+        # picks over all routed experts, and picks on experts held here
+        self._routes = cfg.moe is not None
+        self.moe_assignments = 0
+        self.moe_held_assignments = 0
         # speculative decode quanta: a prompt-lookup drafter proposes up
         # to spec_depth tokens per row; one batched verify forward scores
         # them all (Model.verify_quantum) and the longest matching prefix
@@ -480,7 +492,7 @@ class ServingEngine:
                             self.cache, self.slots)
             if self.chunked_prefill:
                 for cb in self.prefill_buckets:
-                    lg, _ = entry.prefill_chunk(
+                    lg, _, _ = entry.prefill_chunk(
                         self.params, jnp.zeros((1, cb), jnp.int32),
                         self._empty_row, jnp.int32(0), jnp.int32(cb))
                     lg.block_until_ready()
@@ -1119,9 +1131,11 @@ class ServingEngine:
             with span(PREFILL_DISPATCH, model=self.cfg.name, rid=st.req.rid,
                       start=st.done, chunk=c, tokens=valid,
                       finished=int(finished)):
-                logits, st.row_cache = self._prefill_chunk(
+                logits, st.row_cache, routed = self._prefill_chunk(
                     self.params, jnp.asarray(toks)[None], st.row_cache,
                     jnp.int32(st.done), jnp.int32(valid))
+            if self._routes:
+                st.routed.append(routed)
             st.done += valid
             self.prefill_chunks += 1
             self.prefill_tokens += valid
@@ -1136,9 +1150,16 @@ class ServingEngine:
                     else:
                         self.cache = self._row_writer(self.cache, st.row_cache,
                                                       jnp.int32(slot))
-                with span(PREFILL_SYNC, rid=st.req.rid):
-                    # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per admission (finishing chunk only)
-                    first = int(jnp.argmax(logits[0]))
+                with span(PREFILL_SYNC, rid=st.req.rid) as sync:
+                    if self._routes:
+                        # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per admission (finishing chunk only), the prompt's routing counts riding along
+                        first, routed = jax.device_get(
+                            (jnp.argmax(logits[0]), st.routed))
+                        self._count_routing(sync, sum(routed))
+                        first = int(first)
+                    else:
+                        # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per admission (finishing chunk only)
+                        first = int(jnp.argmax(logits[0]))
                 # only the finishing chunk syncs, so only it yields a usable
                 # wall time (intermediate chunks are async dispatches whose
                 # device work this sync may still be draining — keying the
@@ -1260,13 +1281,14 @@ class ServingEngine:
             t0 = time.perf_counter()
             with self._dispatch_span(active, k, steps, bucket, n_left,
                                      "decode"):
-                block, self.cache, _ = qfn(
+                block, self.cache, (_, routed) = qfn(
                     self.params, jnp.asarray(toks), self.cache,
                     jnp.asarray(self.slot_pos), jnp.asarray(n_left))
             self.quantum_calls += 1
             return QuantumHandle(block=block, n_left=n_left, steps=steps,
                                  active=active, t0=t0, traces0=traces0,
-                                 bucket=bucket, tiles=self._entry.key)
+                                 bucket=bucket, tiles=self._entry.key,
+                                 routed=routed)
 
     def _dispatch_span(self, active: list[int], asked: int, steps: int,
                        bucket: int, n_left: np.ndarray, kind: str):
@@ -1356,8 +1378,14 @@ class ServingEngine:
         if handle is None:
             return []
         with span(ENGINE_FINISH_QUANTUM, model=self.cfg.name):
-            with span(QUANTUM_SYNC):
-                if handle.kind == "spec":
+            with span(QUANTUM_SYNC) as sync:
+                if handle.routed is not None:
+                    # veltair: ignore[host-sync-in-hot-path] THE sanctioned per-quantum sync (block and routing counts in one transfer)
+                    block, routed = jax.device_get((handle.block,
+                                                    handle.routed))
+                    self._count_routing(sync, routed)
+                    emitted = accepted = None
+                elif handle.kind == "spec":
                     # ONE fused sync for the whole spec quantum: token
                     # block plus per-row emission/acceptance come back in
                     # a single device->host transfer instead of three
@@ -1371,6 +1399,15 @@ class ServingEngine:
             with span(QUANTUM_BOOKKEEP):
                 return self._bookkeep_quantum(handle, block, emitted,
                                               accepted)
+
+    def _count_routing(self, sync, routed) -> None:
+        """Add one sync's routing counts (assigned, held, peak) to the
+        counters and to its span's arguments."""
+        assigned, held, peak = (int(v) for v in routed)
+        self.moe_assignments += assigned
+        self.moe_held_assignments += held
+        sync.set_metadata(moe_assigned=assigned, moe_held=held,
+                          moe_peak=peak)
 
     def _bookkeep_quantum(self, handle: QuantumHandle, block, emitted,
                           accepted) -> list[Request]:

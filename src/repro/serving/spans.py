@@ -33,11 +33,14 @@ Each span, what it covers, and its arguments:
   (real prompt tokens), ``finished`` (1 on the prompt's last chunk).
 * ``engine.prefill.row_write``: that last chunk's row written into the
   batched cache; ``rid``.
-* ``engine.prefill.sync``: the first-token argmax, one host sync; ``rid``.
+* ``engine.prefill.sync``: the first-token argmax, one host sync; ``rid``;
+  for a model with routed experts also the routing counts of the
+  prompt's chunks (below).
 * ``engine.begin_quantum``: ``begin_quantum``; ``model``.
 * ``engine.quantum.dispatch``: the decode program's call; see below.
 * ``engine.finish_quantum``: ``finish_quantum``; ``model``.
-* ``engine.quantum.sync``: the quantum's one device-to-host transfer.
+* ``engine.quantum.sync``: the quantum's one device-to-host transfer; for
+  a model with routed experts also the quantum's routing counts.
 * ``engine.quantum.bookkeep``: everything after that transfer.
 * ``engine.vcache.build``: a trace or compile in ``VersionCache``;
   ``kind``, ``bucket``.
@@ -50,6 +53,14 @@ row can take from the call: the fused quantum's length, a speculative
 quantum's per-row cap), ``bucket`` (the executable's K-bucket),
 ``tokens`` (sum of the rows' budgets at dispatch) and ``kind``
 (``decode`` or ``spec``).
+
+Routing counts (``engine.prefill.sync``, ``engine.quantum.sync``; set
+when the transfer has brought them back, so the sync itself carries
+them): ``moe_assigned`` (the real tokens' picks over all routed
+experts, summed over MoE layers), ``moe_held`` (those that land on
+experts held on this chip) and ``moe_peak`` (the busiest held expert's
+token count, summed over steps and MoE layers); the engine's counters
+``moe_assignments`` and ``moe_held_assignments`` sum the first two.
 
 ``engine.vcache.build`` covers the lower-and-compile of the AOT decode
 quanta (``kind`` ``quantum`` or ``spec``, ``bucket`` K) and the trace of

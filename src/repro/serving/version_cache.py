@@ -230,10 +230,12 @@ class VersionCache:
         model = self.model
 
         def qfn(params, tokens, cache, pos, n_left):
+            # -> (block, cache, (pos, routing counts or None))
             self.traces += 1
             with dispatch.tile_context(snap):
-                return model.decode_quantum(params, tokens, cache, pos,
-                                            n_left, k)
+                block, cache, pos, routed = model.decode_quantum(
+                    params, tokens, cache, pos, n_left, k)
+            return block, cache, (pos, routed)
 
         vec = jax.ShapeDtypeStruct((int(batch),), jnp.int32)
         cache_sds = jax.tree_util.tree_map(

@@ -147,7 +147,7 @@ def test_window_ring_wrap_chunked_matches_monolithic(models):
         valid = min(c, n - done)
         toks = np.zeros(c, np.int32)
         toks[:valid] = prompt[done:done + valid]
-        lg, cache = model.prefill_chunk(
+        lg, cache, _ = model.prefill_chunk(
             params, {"tokens": jnp.asarray(toks)[None]}, cache,
             jnp.int32(done), jnp.int32(valid))
         done += valid

@@ -360,7 +360,7 @@ def test_frozen_row_cache_contract(arch):
     n_left = np.array([0, 2, k], np.int32)
 
     qfn = jax.jit(model.decode_quantum, static_argnums=(5,))
-    block, cache_q, pos_q = qfn(params, toks0, cache0, pos0,
+    block, cache_q, pos_q, _ = qfn(params, toks0, cache0, pos0,
                                 jnp.asarray(n_left), k)
     np.testing.assert_array_equal(np.asarray(pos_q), plen + n_left)
 

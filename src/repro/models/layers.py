@@ -214,10 +214,68 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return jnp.moveaxis(out, 0, 1).reshape(b, s, *q.shape[2:])
 
 
+def write_cache(leaf: jax.Array, new: jax.Array, index: jax.Array, *,
+                seq_axis: int, layer: jax.Array | None = None,
+                page_table: jax.Array | None = None) -> jax.Array:
+    """Write each row's S new tokens ``new`` (B, S, ...) into a linear KV
+    cache leaf -> the updated leaf.  The leaf holds dense rows
+    (B, ..., T, ...) with the sequence at ``seq_axis``, or a page pool
+    (P, ..., page, ...) addressed through ``page_table`` (B,
+    pages_per_slot).  ``index`` is the position of each row's first new
+    token: a scalar (rows aligned: one contiguous update) or (B,) per-row
+    positions (a scatter).
+
+    With ``layer`` the leaf is a layer scan's whole stack (L, ...) and
+    only layer ``layer``'s tokens are written, in place: no layer-sized
+    slice or copy is made.  The scatter indexes every axis but the last
+    (attention's heads too), so each written window is one token's
+    trailing axis, and the compiler keeps the stack in its argument
+    layout."""
+    b, s = new.shape[:2]
+    new = new.astype(leaf.dtype)
+    lead = () if layer is None else (layer,)
+    index = jnp.asarray(index, jnp.int32)
+    if page_table is None and not index.ndim:
+        upd = jnp.moveaxis(new, 1, seq_axis)
+        start = [jnp.int32(0)] * upd.ndim
+        start[seq_axis] = index
+        if layer is not None:
+            upd, start = upd[None], [layer] + start
+        return jax.lax.dynamic_update_slice(leaf, upd, start)
+    rows = (jnp.broadcast_to(index.reshape(-1), (b,))[:, None]
+            + jnp.arange(s, dtype=jnp.int32)[None, :])          # (B, S)
+    slot = jnp.arange(b, dtype=jnp.int32)[:, None]
+    if page_table is not None:
+        # unmapped spans point at the trash page, so writes past a row's
+        # mapped span land harmlessly there
+        page = leaf.shape[len(lead) + seq_axis]
+        slot, rows = page_table[slot, rows // page], rows % page
+    if seq_axis == 1:
+        return leaf.at[lead + (slot, rows)].set(new)
+    # (B, K, T, D): row, head and position indexed, window D
+    heads = jnp.arange(new.shape[2], dtype=jnp.int32)[:, None]
+    return leaf.at[lead + (slot[:, None], heads, rows[:, None])].set(
+        jnp.swapaxes(new, 1, 2))
+
+
+def cache_layer(leaf: jax.Array, layer: jax.Array | None,
+                width: int | None = None) -> jax.Array:
+    """Layer ``layer`` of a stacked cache leaf (the leaf itself when
+    ``layer`` is None), its last axis cut to ``width``: one slice per
+    reader, which the compiler fuses into the product that reads it."""
+    width = leaf.shape[-1] if width is None else width
+    if layer is None:
+        return leaf if width == leaf.shape[-1] else leaf[..., :width]
+    return jax.lax.dynamic_slice(
+        leaf, (layer,) + (0,) * (leaf.ndim - 1),
+        (1,) + leaf.shape[1:-1] + (width,))[0]
+
+
 def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
               positions: jax.Array, cache: dict | None = None,
               cache_index: jax.Array | None = None,
               page_table: jax.Array | None = None,
+              layer: jax.Array | None = None,
               ) -> tuple[jax.Array, dict | None]:
     """Self-attention with optional KV cache.
 
@@ -234,6 +292,10 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
     kernel is dispatched, a pool gather on the XLA reference path).
     Decode-only — prefill accumulates into dense row caches, which the
     serving engine scatters into pages at admission.
+
+    With ``layer`` the cache leaves are whole stacks ``(L, ...)`` of a
+    layer scan's carry: this layer's tokens are written into the stack in
+    place (:func:`write_cache`) and the returned cache is the stack.
     """
     b, s, m = x.shape
     q = jnp.einsum("bsm,mhd->bshd", x, params["wq"].astype(x.dtype))
@@ -249,61 +311,32 @@ def attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
                    q_positions=qpos, kv_valid_len=s,
                    window=cfg.sliding_window)
         new_cache = None
-    elif page_table is not None:
-        idx = jnp.broadcast_to(
-            jnp.asarray(cache_index, jnp.int32).reshape(-1), (b,))
-        ps_sz = cache["k"].shape[2]
-        if s == 1:
-            bidx = jnp.arange(b, dtype=jnp.int32)
-            phys = page_table[bidx, idx // ps_sz]   # (B,) physical page
-            off = idx % ps_sz
-            ck = cache["k"].at[phys, :, off].set(
-                k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[phys, :, off].set(
-                v[:, 0].astype(cache["v"].dtype))
-        else:
-            # multi-token (speculative verify): scatter each row's S new
-            # tokens through the table.  Unmapped spans point at the trash
-            # page, so over-draft writes land harmlessly there.
-            rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            phys = page_table[bidx, rows // ps_sz]  # (B,S)
-            off = rows % ps_sz
-            ck = cache["k"].at[phys, :, off].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[phys, :, off].set(v.astype(cache["v"].dtype))
-        new_cache = {"k": ck, "v": cv}
-        from repro.kernels import dispatch
-        fn = dispatch.get_paged_attention() if s == 1 else None
-        if fn is not None:
-            y = fn(q, ck, cv, page_table=page_table, q_positions=qpos,
-                   kv_valid_len=idx + 1, window=cfg.sliding_window,
-                   softcap=None)
-        else:
-            def gather(pool):               # (B, K, n_slot * ps, D)
-                pages = jnp.moveaxis(pool[page_table], 1, 2)
-                return pages.reshape(b, pool.shape[1], -1, pool.shape[3])
-            y = attend(q, gather(ck), gather(cv), q_positions=qpos,
-                       kv_valid_len=idx + s, window=cfg.sliding_window,
-                       use_kernel_hook=False)
     else:
-        idx = jnp.asarray(cache_index, jnp.int32)
-        if idx.ndim:
-            # per-row positions: scatter each row's new tokens at its own
-            # index; kv-valid horizon is per-row too
-            rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            ck = cache["k"].at[bidx, :, rows].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, :, rows].set(v.astype(cache["v"].dtype))
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], jnp.swapaxes(k, 1, 2).astype(cache["k"].dtype),
-                (0, 0, idx, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], jnp.swapaxes(v, 1, 2).astype(cache["v"].dtype),
-                (0, 0, idx, 0))
-        y = attend(q, ck, cv, q_positions=qpos, kv_valid_len=idx + s,
-                   window=cfg.sliding_window)
+        ck, cv = (write_cache(cache[n], u, cache_index, seq_axis=2,
+                              layer=layer, page_table=page_table)
+                  for n, u in (("k", k), ("v", v)))
         new_cache = {"k": ck, "v": cv}
+        k_all, v_all = cache_layer(ck, layer), cache_layer(cv, layer)
+        idx = jnp.asarray(cache_index, jnp.int32)
+        if page_table is None:
+            y = attend(q, k_all, v_all, q_positions=qpos,
+                       kv_valid_len=idx + s, window=cfg.sliding_window)
+        else:
+            idx = jnp.broadcast_to(idx.reshape(-1), (b,))
+            from repro.kernels import dispatch
+            fn = dispatch.get_paged_attention() if s == 1 else None
+            if fn is not None:
+                y = fn(q, k_all, v_all, page_table=page_table,
+                       q_positions=qpos, kv_valid_len=idx + 1,
+                       window=cfg.sliding_window, softcap=None)
+            else:
+                def gather(pool):               # (B, K, n_slot * ps, D)
+                    pages = jnp.moveaxis(pool[page_table], 1, 2)
+                    return pages.reshape(b, pool.shape[1], -1,
+                                         pool.shape[3])
+                y = attend(q, gather(k_all), gather(v_all),
+                           q_positions=qpos, kv_valid_len=idx + s,
+                           window=cfg.sliding_window, use_kernel_hook=False)
     y = jnp.einsum("bshd,hdm->bsm", y, params["wo"].astype(x.dtype))
     return y, new_cache
 

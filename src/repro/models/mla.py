@@ -28,7 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import MLAConfig, ModelConfig, YarnConfig
-from repro.models.layers import NEG_INF, apply_norm, apply_rope, norm_specs
+from repro.models.layers import (NEG_INF, apply_norm, apply_rope,
+                                 cache_layer, norm_specs, write_cache)
 from repro.models.params import ParamSpec
 
 
@@ -136,6 +137,7 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
                   positions: jax.Array, cache: dict | None = None,
                   cache_index: jax.Array | None = None,
                   page_table: jax.Array | None = None,
+                  layer: jax.Array | None = None,
                   ) -> tuple[jax.Array, dict | None]:
     """MLA self-attention; cache = {"latent": (B,T,W)}: each position's
     normalized latent ``c_kv`` and its rotated rope key side by side in
@@ -146,7 +148,9 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
     physical page pool ``(n_pages + 1, page_size, W)``: decode scatters
     the new latent into its slot's page and gathers the full horizon
     through the table (latents are already memory-compressed, so the
-    gather reference path is the paged MLA path — no kernel variant)."""
+    gather reference path is the paged MLA path — no kernel variant).
+    With ``layer`` the cache leaf is the layer scan's whole stack, written
+    in place as ``layers.attention`` writes it."""
     mla = cfg.mla
     b, s, m = x.shape
     r, dr = mla.kv_lora_rank, mla.qk_rope_head_dim
@@ -159,37 +163,23 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
                            jnp.zeros((b, s, pad), c_kv.dtype)], -1)
 
     if cache is None:
-        lat_all, kv_len = lat, s
+        lat_all, c_all, kv_len = lat, c_kv, s
         new_cache = None
-    elif page_table is not None:
-        idx = jnp.broadcast_to(
-            jnp.asarray(cache_index, jnp.int32).reshape(-1), (b,))
-        pool = cache["latent"]
-        ps_sz = pool.shape[1]
-        # each row's S new latents through the table (S > 1: speculative
-        # verify); unmapped spans hit the trash page
-        rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        phys = page_table[bidx, rows // ps_sz]   # (B,S)
-        pool = pool.at[phys, rows % ps_sz].set(lat.astype(pool.dtype))
-        new_cache = {"latent": pool}
-        lat_all = pool[page_table].reshape(b, -1, pool.shape[-1])
-        kv_len = idx + s
     else:
-        idx = jnp.asarray(cache_index, jnp.int32)
-        if idx.ndim:
-            # per-row positions (continuous batching): scatter each row's
-            # latents at its own index, per-row kv-valid horizon
-            rows = idx[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-            lat_all = cache["latent"].at[bidx, rows].set(
-                lat.astype(cache["latent"].dtype))
+        pool = write_cache(cache["latent"], lat, cache_index, seq_axis=1,
+                           layer=layer, page_table=page_table)
+        new_cache = {"latent": pool}
+        kv_len = jnp.asarray(cache_index, jnp.int32) + s
+        if page_table is None:
+            # c_kv read through a slice of its own: decode's two products
+            # each fuse their slice, and the layer is never copied out
+            lat_all = cache_layer(pool, layer)
+            c_all = cache_layer(pool, layer, r)
         else:
-            lat_all = jax.lax.dynamic_update_slice(
-                cache["latent"], lat.astype(cache["latent"].dtype),
-                (0, idx, 0))
-        kv_len = idx + s
-        new_cache = {"latent": lat_all}
+            kv_len = jnp.broadcast_to(kv_len.reshape(-1), (b,))
+            lat_all = cache_layer(pool, layer)[page_table].reshape(
+                b, -1, pool.shape[-1])
+            c_all = lat_all[..., :r]
     t = lat_all.shape[1]
     w_uk = params["w_uk"].astype(x.dtype)
     w_uv = params["w_uv"].astype(x.dtype)
@@ -210,11 +200,11 @@ def mla_attention(params: dict, x: jax.Array, *, cfg: ModelConfig,
             scores = jnp.where(mask, scores * scale, NEG_INF)
             probs = jax.nn.softmax(scores, axis=-1)
             ctx = jnp.einsum("bhst,btr->bshr", probs,
-                             lat_all[..., :r].astype(jnp.float32))
+                             c_all.astype(jnp.float32))
             return jnp.einsum("bshr,rhd->bshd", ctx,
                               w_uv.astype(jnp.float32)).astype(x.dtype)
     else:
-        ckv32 = lat_all[..., :r].astype(jnp.float32)
+        ckv32 = c_all.astype(jnp.float32)
         k_nope = jnp.einsum("btr,rhd->bthd", ckv32,
                             w_uk.astype(jnp.float32))         # (B,T,H,dn)
         v = jnp.einsum("btr,rhd->bthd", ckv32,

@@ -162,6 +162,7 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
                  t: jax.Array | int, valid_len: jax.Array | None = None,
                  page_table: jax.Array | None = None,
                  mask: jax.Array | None = None,
+                 layer: jax.Array | None = None,
                  ) -> tuple[jax.Array, dict | None, jax.Array,
                             jax.Array | None]:
     """-> (x, new_cache, aux_loss, routing counts; None outside MoE
@@ -173,7 +174,8 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
     causally invisible until decode reaches its position, and the decode
     write at that position overwrites it first.  ``mask`` (B,S) marks the
     real tokens an MoE layer's routing counts cover
-    (:func:`moe.apply_moe`)."""
+    (:func:`moe.apply_moe`).  ``layer``: the attention cache is the layer
+    scan's whole stack and this is its layer (:func:`layers.write_cache`)."""
     aux, routed = jnp.zeros((), jnp.float32), None
     if kind == "ssm":
         h, new_cache = ssm_mod.mamba2_block(
@@ -197,7 +199,7 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
         h, new_cache = mla_mod.mla_attention(
             params["attn"], xa, cfg=cfg, positions=positions, cache=cache,
             cache_index=t if cache is not None else None,
-            page_table=page_table)
+            page_table=page_table, layer=layer)
     elif kind == "attn_local":
         h, new_cache = _local_attention(cfg, params["attn"], xa,
                                         positions=positions, cache=cache,
@@ -206,7 +208,7 @@ def _apply_block(cfg: ModelConfig, kind: str, params: dict, x: jax.Array, *,
         h, new_cache = L.attention(
             params["attn"], xa, cfg=cfg, positions=positions, cache=cache,
             cache_index=t if cache is not None else None,
-            page_table=page_table)
+            page_table=page_table, layer=layer)
     x = x + h
     x = hint(x, ("batch", "seq", "embed"))
     xm = L.apply_norm(params["ln2"], x, cfg.norm_type)
@@ -460,16 +462,23 @@ class Model:
         aux_total, routed = jnp.zeros((), jnp.float32), _no_routing(cfg)
         new_caches: dict = {}
 
-        def group_fn(gp, x, gcache):
+        def group_fn(gp, x, gcache, stacks=None, layer=None):
+            # ``stacks``: blocks whose cache is the whole stack, written at
+            # ``layer``; other blocks read their layer's slice in ``gcache``
             aux_g, routed_g = jnp.zeros((), jnp.float32), _no_routing(cfg)
             ncache: dict = {}
             for j, kind in enumerate(plan.scan_kinds):
                 key = kind if len(plan.scan_kinds) == 1 else f"{kind}_{j}"
-                c = gcache.get(key) if gcache is not None else None
+                if stacks and key in stacks:
+                    c, li = stacks[key], layer
+                else:
+                    c = gcache.get(key) if gcache is not None else None
+                    li = None
                 x2, nc, a, r = _apply_block(cfg, kind, gp[key], x,
                                             positions=positions, cache=c, t=t,
                                             valid_len=valid_len,
-                                            page_table=page_table, mask=mask)
+                                            page_table=page_table, mask=mask,
+                                            layer=li)
                 x = x2
                 aux_g, routed_g = aux_g + a, _add_routing(routed_g, r)
                 if nc is not None:
@@ -523,14 +532,32 @@ class Model:
                 new_caches["blocks"] = jax.tree_util.tree_map(
                     lambda *cs: jnp.stack(cs), *ncs_list)
             else:
+                # Linear KV leaves (paged_leaf_paths) ride in the carry
+                # whole: each layer writes its new tokens into the stack
+                # in place and reads its own layer from it, so a step
+                # neither slices, restacks nor copies the cache.  State
+                # leaves (no "seq" axis) are rewritten whole every step
+                # and stay scanned in and out.
+                linear = self.paged_leaf_paths()
+                stacks = {key: c for key, c in bcaches.items()
+                          if all(("blocks", key, leaf) in linear
+                                 for leaf in c)}
+                states = {key: c for key, c in bcaches.items()
+                          if key not in stacks}
+
                 def body(carry, xs):
-                    xx, aux, rt = carry
-                    gp, gc = xs
-                    xx, nc, (a, r) = group_fn(gp, xx, gc)
-                    return (xx, aux + a, _add_routing(rt, r)), nc
-                (x, aux_total, routed), ncs = jax.lax.scan(
-                    body, (x, aux_total, routed), (params["blocks"], bcaches))
-                new_caches["blocks"] = ncs
+                    xx, aux, rt, st = carry
+                    gp, gc, li = xs
+                    xx, nc, (a, r) = group_fn(gp, xx, gc, st, li)
+                    return ((xx, aux + a, _add_routing(rt, r),
+                             {key: nc[key] for key in st}),
+                            {key: c for key, c in nc.items()
+                             if key not in st})
+                (x, aux_total, routed, stacks), ncs = jax.lax.scan(
+                    body, (x, aux_total, routed, stacks),
+                    (params["blocks"], states,
+                     jnp.arange(plan.n_groups, dtype=jnp.int32)))
+                new_caches["blocks"] = {**ncs, **stacks}
 
         for i, kind in enumerate(plan.epilogue):
             c = caches.get(f"epi_{i}") if caches is not None else None

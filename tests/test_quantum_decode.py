@@ -21,6 +21,7 @@ from repro.core import cost_model as cm
 from repro.kernels import dispatch
 from repro.models import build_model
 from repro.models.model import cache_batch_axis, path_keys
+from repro.models.params import abstract_params
 from repro.serving.engine import Request, ServingEngine
 
 MAX_LEN = 32
@@ -394,6 +395,15 @@ def test_frozen_row_cache_contract(arch):
                     err_msg=f"{arch} row {row} {key} replayed write")
 
 
+def _compiled_quantum(model, cache, b, **jit_kw):
+    """``decode_quantum`` (4 steps over ``b`` rows) compiled for abstract
+    params and ``cache``."""
+    rows = jax.ShapeDtypeStruct((b,), jnp.int32)
+    return jax.jit(model.decode_quantum, static_argnums=(5,),
+                   **jit_kw).lower(abstract_params(model.param_specs()),
+                                   rows, cache, rows, rows, 4).compile()
+
+
 @pytest.mark.parametrize("arch", ["starcoder2-3b", "mamba2-780m",
                                   "recurrentgemma-2b",
                                   "deepseek-v2-lite-16b"])
@@ -403,14 +413,10 @@ def test_quantum_selects_only_state_leaves(arch):
     entire KV cache), while every recurrent / ring leaf keeps its
     frozen-row select.  Batch and length are picked so no leaf's shape
     coincides with another's."""
-    from repro.models.params import abstract_params
     model = build_model(_reduced(arch))
-    b, t = 5, 48
-    cache = abstract_params(model.cache_specs(b, t))
-    rows = jax.ShapeDtypeStruct((b,), jnp.int32)
-    hlo = jax.jit(model.decode_quantum, static_argnums=(5,)).lower(
-        abstract_params(model.param_specs()), rows, cache, rows, rows,
-        4).compile().as_text()
+    b = 5
+    cache = abstract_params(model.cache_specs(b, 48))
+    hlo = _compiled_quantum(model, cache, b).as_text()
     selected = {tuple(int(d) for d in dims.split(",") if d)
                 for dims in re.findall(r"= \w+\[([\d,]*)\]\S* select\(", hlo)}
     linear = model.paged_leaf_paths()
@@ -420,3 +426,43 @@ def test_quantum_selects_only_state_leaves(arch):
             assert leaf.shape not in selected, (arch, path_keys(path))
         else:
             assert leaf.shape in selected, (arch, path_keys(path))
+
+
+@pytest.mark.parametrize("arch", ["starcoder2-3b", "gemma-2b",
+                                  "deepseek-v2-lite-16b"])
+def test_quantum_writes_stacked_cache_in_place(arch):
+    """Structural guard on the layer scan's cache contract: stacked
+    linear KV leaves ride in the scan's carry and each layer writes its
+    token into the stack in place.  So the compiled decode quantum, cache
+    donated, holds no copy or dynamic-update-slice of a whole stacked
+    leaf and no copy of one layer, and its temporaries stay under half
+    the stacks' bytes (taking the stack in as ``xs`` and out as ``ys``
+    held a restacked copy and copied it into the step carry: 1.25-1.34x
+    here).  GQA, MQA and MLA (whose unstacked first layer stays out).
+
+    Eight layers and 2048 positions, so the cache outweighs the
+    temporaries of weights and activations.  The cache is float32: the
+    CPU has no bfloat16 scatter and converts the whole operand to float32
+    and back, a pass the TPU does not make; ``test_tpu_compile.py``
+    checks the served bfloat16 quantum as the chip compiles it."""
+    model = build_model(dataclasses.replace(_reduced(arch), num_layers=8))
+    cache = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
+        abstract_params(model.cache_specs(8, 2048)))
+    compiled = _compiled_quantum(model, cache, 8, donate_argnums=(2,))
+    linear = model.paged_leaf_paths()
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    stacks = [leaf for path, leaf in flat
+              if path_keys(path)[0] == "blocks" and path_keys(path) in linear]
+    assert stacks, arch
+    whole = {leaf.shape for leaf in stacks}
+    layer = {leaf.shape[1:] for leaf in stacks}
+    hlo = compiled.as_text()
+    for dims, op in re.findall(
+            r"= \w+\[([\d,]*)\]\S* (copy|dynamic-update-slice)\(", hlo):
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        assert shape not in whole, (arch, op, shape)
+        assert op != "copy" or shape not in layer, (arch, op, shape)
+    stack_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in stacks)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < stack_bytes / 2, (arch, temp, stack_bytes)

@@ -1,7 +1,7 @@
 """Benchmark orchestrator — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmarks.common.emit).
-Figure mapping (paper -> section): see DESIGN.md §6.
+Each ``F.fig*`` call below reproduces the paper figure it is named for.
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 """
@@ -18,12 +18,10 @@ def main() -> None:
 
     from benchmarks import bench_figures as F
     from benchmarks import bench_kernels as K
-    from benchmarks import bench_online_serving as O
 
     t0 = time.time()
     print("name,us_per_call,derived")
     K.run_all()
-    O.run_all()
     F.fig4_core_scaling()
     F.fig6_multiversion()
     F.fig7_version_count()

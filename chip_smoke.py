@@ -233,7 +233,7 @@ def phase_single(dev, cfg, params, hw, seed: int) -> None:
                           seed=seed)
     runtime = OnlineRuntime(engine, VeltairPolicy(hw),
                             {cfg.name: cluster_plan(cfg.name, hw)}, hw,
-                            wall_clock=True, scheduler="slo", seed=seed)
+                            wall_clock=True, seed=seed)
     t1 = time.perf_counter()
     runtime.serve(wl)
     serve_s = time.perf_counter() - t1

@@ -83,7 +83,7 @@ def online_engine_demo(hw):
                           seed=1, prompt_len_spread=2)
     # AOT-compile every interference level's code version: each switch
     # during serve() is then a dictionary swap, never a re-jit stall
-    engine.warmup(prompt_lens=tuple(sorted(set(wl.prompt_lengths()))))
+    engine.warmup()
     t0 = time.time()
     runtime = OnlineRuntime(engine, policy, plans, hw)
     m_eng = runtime.serve(wl)
@@ -183,7 +183,7 @@ def speculative_demo():
     def serve(speculative):
         eng = ServingEngine(cfg, params, batch_slots=2, max_len=160,
                             speculative=speculative)
-        eng.warmup(prompt_lens=tuple(len(p) for p in prompts))
+        eng.warmup()
         reqs = [Request(rid=i, prompt=p, max_new_tokens=96)
                 for i, p in enumerate(prompts)]
         pending = list(reqs)

@@ -34,8 +34,8 @@ The paper's runtime loop, on the real execution path:
    the pool and ``set_interference_level`` swaps the engine to the code
    version compiled for the estimated pressure (a dictionary swap of
    precompiled executables after :meth:`ClusterRuntime.warmup`).
-5. **Dispatch** — in fused mode (default) each granted engine's whole
-   quantum runs as ONE on-device executable
+5. **Dispatch** — each granted engine's whole quantum runs as ONE
+   on-device executable
    (:meth:`~repro.serving.engine.ServingEngine.begin_quantum`), and the
    tick issues every engine's quantum *before* blocking on any of them
    (:meth:`~repro.serving.engine.ServingEngine.finish_quantum`), so
@@ -43,11 +43,10 @@ The paper's runtime loop, on the real execution path:
    through Python — one host sync per engine per quantum.
 
 Time: a virtual clock advances ``step_dt`` per executed decode step —
-in fused mode a tick spans the longest quantum it dispatched, and
-completions inside a quantum keep exact per-step virtual finish times.
-``wall_clock=True`` charges measured wall time instead (version-switch
-stalls included, as in ``OnlineRuntime``).  ``fused=False`` restores the
-per-step dispatch loop (the measured baseline).
+a tick spans the longest quantum it dispatched, and completions inside
+a quantum keep exact per-step virtual finish times.  ``wall_clock=True``
+charges measured wall time instead (version-switch stalls included, as
+in ``OnlineRuntime``).
 """
 from __future__ import annotations
 
@@ -103,7 +102,6 @@ class _TenantState:
     busy: float = 0.0              # occupancy-weighted unit-time
     alloc: float = 0.0             # granted unit-time
     records: list = dataclasses.field(default_factory=list)
-    prefill_last: bool = False     # prefill/decode alternation state
     prefill_quanta: int = 0        # prefill chunks dispatched
     ttft: dict = dataclasses.field(default_factory=dict)   # rid -> ttft
 
@@ -186,17 +184,13 @@ class ClusterRuntime:
     def __init__(self, tenants: list[EngineTenant], policy: Policy,
                  hw: cm.HardwareSpec, *, step_dt: float = 1e-3,
                  wall_clock: bool = False, max_steps: int = 200_000,
-                 seed: int = 0, fused: bool = True,
-                 scheduler: str = "slo",
+                 seed: int = 0,
                  admission: AdmissionController | None = None,
                  tiers: dict[str, TierSpec] | None = None,
                  counter_source: str = "oracle",
                  refit_proxy: bool | None = None):
         if len({t.name for t in tenants}) != len(tenants):
             raise ValueError("tenant names must be unique")
-        if scheduler not in ("slo", "fifo"):
-            raise ValueError(f"scheduler must be 'slo' or 'fifo', "
-                             f"got {scheduler!r}")
         if counter_source not in ("oracle", "measured"):
             raise ValueError(f"counter_source must be 'oracle' or "
                              f"'measured', got {counter_source!r}")
@@ -206,8 +200,6 @@ class ClusterRuntime:
         self.step_dt = step_dt
         self.wall_clock = wall_clock
         self.max_steps = max_steps
-        self.fused = fused
-        self.scheduler = scheduler
         self.admission = admission       # None = admit everything (legacy)
         self.book = DeadlineBook(tiers)
         # counter provenance per engine: "measured" reads each tenant's
@@ -234,14 +226,13 @@ class ClusterRuntime:
         self._demand_cache: dict[tuple[str, int], tuple] = {}
 
     # ------------------------------------------------------------------
-    def warmup(self, prompt_lens: tuple[int, ...] = (),
+    def warmup(self,
                quantum_buckets: tuple[int, ...] | None = None) -> dict:
         """AOT-compile every engine's full level table AND its fused
         K-bucket quantum executables (level switches during serve()
         become dictionary swaps; the first fused dispatch never traces).
         Returns per-tenant version-cache stats."""
-        return {t.name: t.engine.warmup(prompt_lens=prompt_lens,
-                                        quantum_buckets=quantum_buckets)
+        return {t.name: t.engine.warmup(quantum_buckets=quantum_buckets)
                 for t in self.tenants}
 
     def tenant_prompts(self, wl: Workload) -> dict[str, np.ndarray]:
@@ -386,7 +377,7 @@ class ClusterRuntime:
                               prompt=prompts[t.name][rid, :lens[rid]],
                               max_new_tokens=wl.max_new_tokens,
                               tier=tier_of(t.name))
-                if self.scheduler == "slo" and self.admission is not None:
+                if self.admission is not None:
                     entry = self.book.entry(rid)
                     pages_needed, pages_free = t.engine.admission_pages(
                         req.prompt, wl.max_new_tokens)
@@ -430,8 +421,6 @@ class ClusterRuntime:
                         self.tenant_conflicts[t.name] += 1
                     break
                 meta[rid] = (t.name, at, now)
-                if req.output:                    # monolithic admission
-                    st.ttft[rid] = now - at
                 st.pending.popleft()
             active = [meta[r.rid][2] for r in t.engine.slot_req
                       if r is not None]
@@ -495,7 +484,7 @@ class ClusterRuntime:
                                 if t.engine.active_slots]
                 need = [task for task in active_tasks
                         if self._state[task.tenant].grant == 0]
-                if self.scheduler == "slo" and tiered:
+                if tiered:
                     # tiered serve: grants go out in earliest-deadline order
                     # (the engine whose tightest query has least slack plans
                     # first, so it gets units before the pool runs dry)
@@ -513,10 +502,9 @@ class ClusterRuntime:
                     {t.name: self._state[t.name].grant for t in self.tenants})
 
                 # dispatch phase: launch every granted engine's quantum BEFORE
-                # blocking on any of them — in fused mode begin_quantum
-                # returns without a host sync, so N co-located engines'
-                # device work overlaps instead of serializing through the
-                # Python loop
+                # blocking on any of them — begin_quantum returns without a
+                # host sync, so N co-located engines' device work overlaps
+                # instead of serializing through the Python loop
                 granted = [t for t in active if self._state[t.name].grant > 0]
                 # lockstep tick quantum: every granted engine dispatches the
                 # same number of steps this tick (the smallest outstanding
@@ -533,39 +521,25 @@ class ClusterRuntime:
                         # pending); time still advances below, so the next tick
                         # re-plans instead of spinning
                         continue
-                    # per-engine prefill/decode pick.  FIFO: strict
-                    # alternation — an engine with a prompt mid-prefill
-                    # spends every other quantum (or every quantum, if
-                    # nothing is decodable) on one prefill chunk, so
-                    # admissions are metered without starving its decodes.
-                    # SLO: earliest-deadline pick over the engine's prefill
-                    # queue and decode backlog (TTFT-urgent chunks preempt).
-                    pf_slot = None
-                    k_dispatch = q_tick
-                    if self.scheduler == "slo":
-                        with span(RUNTIME_PICK, tenant=t.name):
-                            pick = pick_quantum(t.engine, self.book, now,
-                                                self.step_dt, max(q_tick, 1))
-                        do_prefill = pick is not None and pick[0] == "prefill"
-                        if do_prefill:
-                            pf_slot = pick[1]
-                        elif pick is not None:
-                            k_dispatch = min(q_tick, pick[1]) or 1
-                    else:
-                        do_prefill = t.engine.should_prefill(st.prefill_last)
-                        st.prefill_last = do_prefill
-                    if do_prefill:
+                    # per-engine prefill/decode pick: earliest deadline
+                    # over the engine's prefill queue and decode backlog
+                    # (TTFT-urgent chunks preempt)
+                    with span(RUNTIME_PICK, tenant=t.name):
+                        pick = pick_quantum(t.engine, self.book, now,
+                                            self.step_dt, max(q_tick, 1))
+                    if pick is not None and pick[0] == "prefill":
                         occupancy = 1.0 / t.engine.slots   # the prefilling row
-                        pf = t.engine.prefill_step(pf_slot)
+                        pf = t.engine.prefill_step(pick[1])
                         st.prefill_quanta += 1
                         launched.append((t, st, None, occupancy, pf))
                         continue
+                    k_dispatch = (q_tick if pick is None
+                                  else min(q_tick, pick[1]) or 1)
                     # decode occupancy: slots still mid-prefill are skipped by
                     # the decode quantum and must not be charged as busy
                     occupancy = (t.engine.active_slots
                                  - t.engine.prefill_pending) / t.engine.slots
-                    handle = (t.engine.begin_quantum(k_dispatch)
-                              if self.fused else None)
+                    handle = t.engine.begin_quantum(k_dispatch)
                     launched.append((t, st, handle, occupancy, None))
 
                 # collect phase: one host sync per engine per quantum
@@ -581,18 +555,13 @@ class ClusterRuntime:
                         row_tokens = 1.0          # the one row being prefilled
                         if pf.finished:
                             prefill_done.append((st, pf.rid))
-                    elif self.fused:
+                    else:
                         fin = t.engine.finish_quantum(handle)
                         steps = handle.steps if handle is not None else 1
                         row_steps = (handle.row_steps if handle is not None
                                      else {})
                         row_tokens = (float(handle.n_left.sum())
                                       if handle is not None else 0.0)
-                    else:
-                        fin = t.engine.step()
-                        steps = 1
-                        row_steps = {}
-                        row_tokens = occupancy * t.engine.slots
                     max_run = max(max_run, steps)
                     held.append((st, st.grant, occupancy, steps, row_tokens,
                                  t.engine.slots))

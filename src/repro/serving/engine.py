@@ -10,14 +10,13 @@ reference.  This is the engine the examples drive on CPU with reduced
 models; at pod scale the same functions are jitted with the serve-mode
 shardings (launch/serve.py).
 
-Admission is chunked and length-bucketed (``chunked_prefill=True``):
-``admit_request`` validates the prompt and queues power-of-two-bucketed
-prefill chunks; ``prefill_step`` runs one chunk — the prefill-side
-dispatch quantum — into the slot's accumulating row cache.  Compiled
-prefill shapes are the bucket table, never the prompt-length
-distribution, so mixed-length traffic performs zero post-warmup
-retraces, and the runtimes interleave chunks with decode quanta so a
-long prompt cannot stall co-resident decodes (docs/ARCHITECTURE.md §5).
+Admission is chunked and length-bucketed: ``admit_request`` validates
+the prompt and queues power-of-two-bucketed prefill chunks;
+``prefill_step`` runs one chunk — the prefill-side dispatch quantum —
+into the slot's accumulating row cache.  Compiled prefill shapes are
+the bucket table, never the prompt-length distribution, so mixed-length
+traffic performs zero post-warmup retraces, and the runtimes order
+chunks against decode quanta by deadline (docs/ARCHITECTURE.md §5).
 
 The VELTAIR integration point: ``set_interference_level`` selects the
 code version the adaptive compiler produced for that pressure — either
@@ -38,7 +37,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -176,10 +174,9 @@ class QuantumHandle:
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, *, batch_slots: int = 4,
-                 max_len: int = 256, greedy: bool = True,
+                 max_len: int = 256,
                  version_sets: list | None = None,
                  quantum_buckets: tuple[int, ...] = QUANTUM_BUCKETS,
-                 chunked_prefill: bool = True,
                  prefill_chunk_len: int = PREFILL_CHUNK_LEN,
                  page_size: int | None = None, n_pages: int | None = None,
                  page_reserve: str = "worst", prefix_sharing: bool = True,
@@ -191,7 +188,6 @@ class ServingEngine:
         self.params = params
         self.slots = batch_slots
         self.max_len = max_len
-        self.greedy = greedy
         # paged KV cache: linear-attention cache leaves live in a global
         # page pool indexed through a per-slot page table; memory becomes
         # a scheduler-visible dimension (PagePool commitments gate
@@ -245,10 +241,9 @@ class ServingEngine:
             self.cache = self.model.init_cache(batch_slots, max_len)
         self.slot_req: list[Request | None] = [None] * batch_slots
         self.slot_pos = np.zeros(batch_slots, np.int32)
-        # chunked, length-bucketed admission (the scheduled-prefill path):
-        # chunk sizes are powers of two <= prefill_chunk_len, clamped so a
-        # padded tail can never write past the cache's max_len rows
-        self.chunked_prefill = chunked_prefill
+        # chunked, length-bucketed admission: chunk sizes are powers of
+        # two <= prefill_chunk_len, clamped so a padded tail can never
+        # write past the cache's max_len rows
         self.prefill_chunk_len = min(_next_pow2(prefill_chunk_len),
                                      _next_pow2(max_len + 1) // 2 or 1)
         self.prefill_buckets = tuple(
@@ -354,7 +349,6 @@ class ServingEngine:
     def _use_version(self, tiles: dict) -> None:
         entry = self.version_cache.get(tiles)
         self._entry = entry
-        self._prefill_one = entry.prefill
         self._prefill_chunk = entry.prefill_chunk
         self._decode = entry.decode
 
@@ -426,27 +420,22 @@ class ServingEngine:
         self.interference_level = itf.level
         return {op: dict(kw) for op, kw in tiles.items()}
 
-    def warmup(self, prompt_lens: tuple[int, ...] = (),
-               levels: list[float] | None = None,
+    def warmup(self, levels: list[float] | None = None,
                quantum_buckets: tuple[int, ...] | None = None) -> dict:
         """Ahead-of-time build AND execute the executables of every
         interference level (default: the full NUM_LEVELS grid), so later
         ``set_interference_level`` calls are dictionary swaps and the step
         that follows them never traces or compiles.
 
-        Decode is shape-stable and always warmed.  On the chunked
-        admission path every prefill-chunk bucket is warmed too, so
-        mixed-length traffic never retraces — ``prompt_lens`` is only
-        needed for the monolithic (``chunked_prefill=False``) path, whose
-        prefill specializes per exact length.  Every fused K-bucket
-        executable is AOT-compiled alongside (against abstract cache
-        shapes — no decode steps run for them), so the first
-        ``step_quantum`` after warmup never traces either; pass
-        ``quantum_buckets`` to warm a subset.  Memory: one compiled
+        Decode is shape-stable and always warmed, and so is every
+        prefill-chunk bucket, so mixed-length traffic never retraces.
+        Every fused K-bucket executable is AOT-compiled alongside
+        (against abstract cache shapes — no decode steps run for them),
+        so the first ``step_quantum`` after warmup never traces either;
+        pass ``quantum_buckets`` to warm a subset.  Memory: one compiled
         decode + one fused executable per (distinct tile configuration,
-        K-bucket), one chunked prefill per (configuration, chunk bucket),
-        plus one compiled prefill per (configuration, length in
-        ``prompt_lens``).  Returns the version-cache stats snapshot."""
+        K-bucket), plus one chunked prefill per (configuration, chunk
+        bucket).  Returns the version-cache stats snapshot."""
         if levels is None:
             levels = [cm.grid_point(i) for i in range(cm.NUM_LEVELS)]
         buckets = (self.quantum_buckets if quantum_buckets is None
@@ -490,16 +479,10 @@ class ServingEngine:
                         self.version_cache.spec_quantum(
                             entry, k, self.spec_depth, self.params,
                             self.cache, self.slots)
-            if self.chunked_prefill:
-                for cb in self.prefill_buckets:
-                    lg, _, _ = entry.prefill_chunk(
-                        self.params, jnp.zeros((1, cb), jnp.int32),
-                        self._empty_row, jnp.int32(0), jnp.int32(cb))
-                    lg.block_until_ready()
-            for plen in prompt_lens:
-                lg, _ = entry.prefill(
-                    self.params, jnp.zeros((1, int(plen)), jnp.int32),
-                    self._empty_row)
+            for cb in self.prefill_buckets:
+                lg, _, _ = entry.prefill_chunk(
+                    self.params, jnp.zeros((1, cb), jnp.int32),
+                    self._empty_row, jnp.int32(0), jnp.int32(cb))
                 lg.block_until_ready()
         if self.paged:
             # warm the engine-level paged helpers too (first admission /
@@ -709,7 +692,7 @@ class ServingEngine:
         assert self.pool is not None
         n = len(prompt)
         shared: list = []
-        if self.prefix_sharing and self.chunked_prefill:
+        if self.prefix_sharing:
             shared, _ = self._probe_prefix(prompt)
         horizon = (n + max(int(max_new_tokens), 1)
                    if self.page_reserve == "worst" else n + 1)
@@ -729,7 +712,7 @@ class ServingEngine:
         pool, ps = self.pool, self.page_size
         shared: list[tuple[int, int]] = []
         partial: tuple | None = None
-        if self.prefix_sharing and self.chunked_prefill:
+        if self.prefix_sharing:
             shared, partial = self._probe_prefix(req.prompt)
         horizon = (n + max(req.max_new_tokens, 1)
                    if self.page_reserve == "worst" else n + 1)
@@ -1002,17 +985,14 @@ class ServingEngine:
         """Reserve a slot for ``req`` and queue its prefill chunks WITHOUT
         executing them — callers meter prefill by pumping
         :meth:`prefill_step` (runtimes interleave it with decode quanta).
-        ``drain=True`` additionally pumps queued chunks (FIFO) until this
-        request's first token is out — the synchronous convenience path
-        for tests/examples (the old ``add_request``).
+        ``drain=True`` additionally pumps queued chunks (oldest first)
+        until this request's first token is out — the synchronous
+        convenience path for tests/examples.
 
         Returns False when no slot is free (retry later).  Raises
         ``ValueError`` for prompts the cache row cannot hold — empty, or
         ``len(prompt) >= max_len`` (a clamped row write would silently
-        corrupt the cache); such a request must be dropped, not retried.
-
-        With ``chunked_prefill=False`` the whole prompt prefills here,
-        monolithically and per-exact-length (the reference path)."""
+        corrupt the cache); such a request must be dropped, not retried."""
         with span(ENGINE_ADMIT, model=self.cfg.name, rid=req.rid):
             n = len(req.prompt)
             if n < 1 or n >= self.max_len:
@@ -1032,45 +1012,19 @@ class ServingEngine:
                 start, row = admitted
             self.slot_req[slot] = req
             self.slot_pos[slot] = n
-            if self.chunked_prefill:
-                self._prefill[slot] = _PrefillState(
-                    req=req, row_cache=row,
-                    schedule=self._prefill_schedule(n, start), done=start)
-                self._note_occupancy()
-                if drain:
-                    while not req.output:
-                        self.prefill_step()
-                return True
-            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            logits, row_cache = self._prefill_one(self.params, toks,
-                                                  self._empty_row)
-            if self.paged:
-                self.cache = self._paged_row_writer(
-                    self.cache, row_cache, jnp.int32(slot),
-                    jnp.asarray(self._write_table(slot)))
-                self._publish_slot_pages(slot, req)
-            else:
-                self.cache = self._row_writer(self.cache, row_cache,
-                                              jnp.int32(slot))
-            # veltair: ignore[host-sync-in-hot-path] the ONE sanctioned sync per (monolithic) admission: the prompt's first sampled token
-            first = int(jnp.argmax(logits[0]))   # prompt's first sampled token
-            self.host_syncs += 1
-            self.tokens_decoded += 1
-            self.prefill_tokens += n
+            self._prefill[slot] = _PrefillState(
+                req=req, row_cache=row,
+                schedule=self._prefill_schedule(n, start), done=start)
             self._note_occupancy()
-            req.output.append(first)
+            if drain:
+                while not req.output:
+                    self.prefill_step()
             return True
 
     @property
     def prefill_pending(self) -> int:
         """Slots whose prompts are not fully prefilled yet."""
         return len(self._prefill)
-
-    @property
-    def decode_ready(self) -> bool:
-        """Any occupied slot past prefill (eligible for decode quanta)."""
-        return any(r is not None and i not in self._prefill
-                   for i, r in enumerate(self.slot_req))
 
     def prefill_queue(self) -> list[tuple[int, int, int]]:
         """Slots mid-prefill, FIFO order: (slot, rid, chunks_left).  The
@@ -1092,21 +1046,11 @@ class ServingEngine:
             out.append((i, req.rid, max(1, min(need, room))))
         return out
 
-    def should_prefill(self, last_was_prefill: bool) -> bool:
-        """Strict prefill/decode alternation (shared by both runtimes):
-        spend this quantum on a prefill chunk when a prompt is
-        mid-prefill and either nothing is decodable yet or the previous
-        quantum was a decode — admissions are metered without starving
-        co-resident decodes, and a long prompt steals at most every
-        other quantum."""
-        return bool(self._prefill) and (not self.decode_ready
-                                        or not last_was_prefill)
-
     def prefill_step(self, slot: int | None = None) -> PrefillQuantum | None:
         """Run ONE prefill chunk — the prefill-side dispatch quantum —
-        for ``slot``, or for the oldest slot still prefilling (FIFO)
-        when ``slot`` is None.  SLO schedulers pass the slot whose TTFT
-        deadline is tightest; FIFO callers pass nothing.
+        for ``slot``, or for the oldest slot still prefilling when
+        ``slot`` is None.  The runtimes pass the slot whose TTFT deadline
+        is tightest; ``drain`` and ``run_to_completion`` pass nothing.
 
         The chunk prefills into the slot's accumulating batch-1 row cache
         at its start-position offset; only the final chunk pays a
@@ -1179,18 +1123,6 @@ class ServingEngine:
             return PrefillQuantum(slot=slot, rid=st.req.rid, chunk=c,
                                   tokens=valid, finished=finished)
 
-    def add_request(self, req: Request) -> bool:
-        """Deprecated alias for ``admit_request(req, drain=True)``.
-
-        Chunked and monolithic admission produce token-identical
-        requests; chunked just runs through the bucket table."""
-        warnings.warn(
-            "ServingEngine.add_request is deprecated; use "
-            "admit_request(req, drain=True) (or admit_request + "
-            "prefill_step to meter prefill as scheduled quanta)",
-            DeprecationWarning, stacklevel=2)
-        return self.admit_request(req, drain=True)
-
     def step(self) -> list[Request]:
         """One decode step for every active slot; returns finished reqs.
         Slots still mid-prefill are not decodable and are skipped.
@@ -1218,10 +1150,11 @@ class ServingEngine:
         further calls with the leftover (one sync each).
 
         With ``fused=False`` one plain decode step is dispatched (``k``
-        is ignored beyond being positive) — the per-step reference path,
-        kept on the same handle protocol so both modes do identical
-        bookkeeping in :meth:`finish_quantum`.  Returns ``None`` when no
-        slot is active (slots still mid-prefill are not decodable)."""
+        is ignored beyond being positive).  No runtime takes this path:
+        it is the fused quantum's test oracle, kept on the same handle
+        protocol so both modes do identical bookkeeping in
+        :meth:`finish_quantum`.  Returns ``None`` when no slot is active
+        (slots still mid-prefill are not decodable)."""
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and i not in self._prefill]
         if not active or k <= 0:

@@ -176,13 +176,12 @@ class OnlineRuntime:
     Each iteration: admit due arrivals into free slots, derive the live
     interference level from the policy, apply it to the engine's kernel
     dispatch, dispatch the next layer-block-sized quantum as ONE fused
-    on-device call (``fused=True``, the default) or a single batched
-    decode step (``fused=False``, the per-step baseline), and record
-    completions as QueryRecords against each tenant's QoS deadline.
+    on-device call, and record completions as QueryRecords against each
+    tenant's QoS deadline.
 
-    In fused mode the policy's layer-block plan (``plan_chunk_at``)
-    sets the dispatch quantum: the scheduler only intervenes at block
-    boundaries, and the engine syncs the host exactly once per quantum
+    The policy's layer-block plan (``plan_chunk_at``) sets the dispatch
+    quantum: the scheduler only intervenes at block boundaries, and the
+    engine syncs the host exactly once per quantum
     (``engine.host_syncs`` / ``engine.tokens_per_sync`` measure it).
     Completions inside a quantum keep exact virtual finish times — the
     engine reports per-request executed steps.
@@ -191,37 +190,27 @@ class OnlineRuntime:
     *chunks* (``engine.admit_request`` + ``engine.prefill_step``), and
     each chunk is one scheduled quantum — it passes through the same
     counter poll / level switch as a decode quantum, advances the
-    virtual clock, and is charged to ``busy``/``alloc``.  Prefill and
-    decode quanta strictly alternate while both have work, so a long
-    prompt stalls co-resident decodes for at most one chunk, and TTFT
+    virtual clock, and is charged to ``busy``/``alloc``, so TTFT
     (``QueryRecord.ttft_s`` / ``ServingMetrics.avg_ttft_s``) is real
     virtual time, not zero.  Inadmissible prompts (``len >= max_len``)
     are rejected at admission and counted as conflicts.
 
-    Scheduling (``scheduler=``): ``"slo"`` (default) picks every quantum
-    by earliest deadline over the prefill queue and decode backlog
-    (serving.slo.pick_quantum) — TTFT-urgent prefill chunks preempt
-    decode quanta, batch-tier decodes yield — and admissions go in
-    earliest-deadline order through the optional
-    :class:`~repro.serving.slo.AdmissionController` (shed/defer counted
-    in ``ServingMetrics.shed_queries``/``deferred_queries``).  ``"fifo"``
-    keeps the legacy strict prefill/decode alternation and
-    arrival-order admission.  Both orderings retire every request with
-    identical per-request token streams — scheduling reorders quanta,
-    never changes what a row computes."""
+    Scheduling: every quantum is picked by earliest deadline over the
+    prefill queue and decode backlog (serving.slo.pick_quantum) —
+    TTFT-urgent prefill chunks preempt decode quanta, batch-tier decodes
+    yield — and admissions go in earliest-deadline order through the
+    optional :class:`~repro.serving.slo.AdmissionController` (shed/defer
+    counted in ``ServingMetrics.shed_queries``/``deferred_queries``).
+    Scheduling reorders quanta, never changes what a row computes."""
 
     def __init__(self, engine: ServingEngine, policy: Policy,
                  plans: dict[str, ModelPlan], hw: cm.HardwareSpec, *,
                  step_dt: float = 1e-3, wall_clock: bool = False,
                  max_steps: int = 200_000, seed: int = 0,
-                 fused: bool = True, scheduler: str = "slo",
                  admission: AdmissionController | None = None,
                  tiers: dict[str, TierSpec] | None = None,
                  counter_source: str = "oracle",
                  refit_proxy: bool | None = None):
-        if scheduler not in ("slo", "fifo"):
-            raise ValueError(f"scheduler must be 'slo' or 'fifo', "
-                             f"got {scheduler!r}")
         if counter_source not in ("oracle", "measured"):
             raise ValueError(f"counter_source must be 'oracle' or "
                              f"'measured', got {counter_source!r}")
@@ -232,8 +221,6 @@ class OnlineRuntime:
         self.step_dt = step_dt
         self.wall_clock = wall_clock
         self.max_steps = max_steps
-        self.fused = fused
-        self.scheduler = scheduler
         self.admission = admission       # None = admit everything (legacy)
         self.book = DeadlineBook(tiers)
         # counter provenance: "oracle" synthesizes samples from the demand
@@ -258,9 +245,8 @@ class OnlineRuntime:
         self.steps = 0
         self.quanta = 0                  # decode dispatch quanta issued
         self.prefill_quanta = 0          # prefill-chunk quanta issued
-        self._prefill_last = False       # prefill/decode alternation state
         self._ttft: dict[int, float] = {}   # rid -> time to first token
-        self._cursor = 0                 # layer-block cursor (fused mode)
+        self._cursor = 0                 # layer-block cursor
         self._cursor_n = 1               # cursor modulus (head plan layers)
         # wall time spent inside set_interference_level — with a warmed
         # version cache this is pure dictionary swaps; without it, this is
@@ -329,23 +315,18 @@ class OnlineRuntime:
     def _admission_pass(self, pending: list, wl: Workload, prompts, lens,
                         meta: dict, rejected: set, deferred_rids: set,
                         shed_rids: set, now: float) -> None:
-        """Admit due requests into free slots.  FIFO mode walks the queue
-        in arrival order and stops at the first full-engine failure
-        (legacy).  SLO mode walks it in earliest-deadline order —
-        an urgent late arrival jumps the queue — and consults the
+        """Admit due requests into free slots, in earliest-deadline order
+        — an urgent late arrival jumps the queue — consulting the
         admission controller, which may shed (drop + count) or defer
         (skip this pass + count) a request before QoS collapses."""
-        if self.scheduler == "slo":
-            order = sorted(pending,
-                           key=lambda p: (self.book.entry(p[2]).deadline,
-                                          p[0], p[2]))
-        else:
-            order = list(pending)
+        order = sorted(pending,
+                       key=lambda p: (self.book.entry(p[2]).deadline,
+                                      p[0], p[2]))
         for t, tenant, rid in order:
             req = Request(rid=rid, prompt=prompts[rid, :lens[rid]],
                           max_new_tokens=wl.max_new_tokens,
                           tier=wl.tier_of(tenant))
-            if self.scheduler == "slo" and self.admission is not None:
+            if self.admission is not None:
                 entry = self.book.entry(rid)
                 pages_needed, pages_free = self.engine.admission_pages(
                     req.prompt, wl.max_new_tokens)
@@ -391,8 +372,6 @@ class OnlineRuntime:
                     self.conflicts += 1
                 break
             meta[rid] = (tenant, t, now)
-            if req.output:               # monolithic engines prefill
-                self._ttft[rid] = now - t   # inside admit_request
             pending.remove((t, tenant, rid))
 
     def serve(self, wl: Workload) -> ServingMetrics:
@@ -464,33 +443,18 @@ class OnlineRuntime:
             self.compile_time_s += time.perf_counter() - t0
             self.level_trace.append(level)
 
-            # quantum pick.  FIFO mode: prefill chunks and decode quanta
-            # strictly alternate while both have work — a long prompt
-            # never stalls co-resident decodes for more than one chunk
-            # (the granularity claim, applied to the admission path).
-            # SLO mode: earliest-deadline order over both queues — a
+            # quantum pick: earliest-deadline order over both queues — a
             # TTFT-urgent prefill chunk preempts decode quanta, batch-
             # tier decodes yield, and a decode quantum's length is
-            # capped by the tightest pending TTFT deadline.
-            k_cap = self._plan_quantum(meta, sample, now) if self.fused \
-                else 1
-            pf_slot = None
-            if self.scheduler == "slo":
-                pick = pick_quantum(self.engine, self.book, now,
-                                    self.step_dt, k_cap)
-                do_prefill = pick is not None and pick[0] == "prefill"
-                if do_prefill:
-                    pf_slot = pick[1]
-                elif pick is not None:
-                    k_cap = pick[1]
-            else:
-                do_prefill = self.engine.should_prefill(self._prefill_last)
-                self._prefill_last = do_prefill
+            # capped by the tightest pending TTFT deadline
+            k_cap = self._plan_quantum(meta, sample, now)
+            pick = pick_quantum(self.engine, self.book, now,
+                                self.step_dt, k_cap)
             handle = None
             finished: list = []
             pf = None
-            if do_prefill:
-                pf = self.engine.prefill_step(pf_slot)
+            if pick is not None and pick[0] == "prefill":
+                pf = self.engine.prefill_step(pick[1])
                 steps_run = 1
                 self.prefill_quanta += 1
                 if pf is not None:
@@ -498,8 +462,9 @@ class OnlineRuntime:
                     self.sched_trace.append(
                         ("prefill", pf.rid,
                          tier.tier if tier is not None else None, now))
-            elif self.fused:
-                handle = self.engine.begin_quantum(k_cap)
+            else:
+                handle = self.engine.begin_quantum(
+                    k_cap if pick is None else pick[1])
                 if handle is not None:
                     self.sched_trace.append(("decode", tuple(
                         self.engine.slot_req[i].rid
@@ -510,17 +475,6 @@ class OnlineRuntime:
                     self._cursor = (self._cursor + handle.steps) \
                         % self._cursor_n
                 self.quanta += 1
-            else:
-                handle = self.engine.begin_quantum(1, fused=False)
-                if handle is not None:
-                    self.sched_trace.append(("decode", tuple(
-                        self.engine.slot_req[i].rid
-                        for i in handle.active), now))
-                finished = self.engine.finish_quantum(handle)
-                handle = None           # per-step: legacy time accounting
-                steps_run = 1
-                self.quanta += 1        # a per-step dispatch is a 1-step
-                                        # quantum (comparable records)
             dt = (time.perf_counter() - t0) if self.wall_clock \
                 else self.step_dt * steps_run
             self.steps += steps_run

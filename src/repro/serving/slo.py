@@ -4,7 +4,7 @@ VELTAIR's headline metric is queries served *under a QoS target*
 (paper §6), and PREMA's latency-tier scheduling is the model: every
 schedulable unit — a prefill chunk or a fused decode quantum — carries
 a deadline-derived urgency, and the runtime picks the next quantum by
-earliest deadline instead of FIFO alternation.  Three pieces live here,
+earliest deadline instead of arrival order.  Three pieces live here,
 shared by ``OnlineRuntime`` and ``ClusterRuntime``:
 
 * :class:`DeadlineBook` — per-request deadline bookkeeping.  A request's

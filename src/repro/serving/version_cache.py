@@ -5,9 +5,10 @@ pressure is cheap enough to do *online*.  A naive engine pays a full
 ``jax.jit`` retrace every time the tile overrides change — exactly the
 overhead adaptive compilation is supposed to amortize.  This module makes
 the switch a dictionary lookup: one :class:`VersionEntry` per tile
-configuration holds its own jitted prefill/decode callables, traced under
-a :func:`repro.kernels.dispatch.tile_context` that bakes that entry's
-tiles into the executable.  Because every entry owns its trace cache,
+configuration holds its own jitted prefill-chunk/decode callables,
+traced under a :func:`repro.kernels.dispatch.tile_context` that bakes
+that entry's tiles into the executable.  Because every entry owns its
+trace cache,
 
   * revisiting a level never retraces (jit hits its own cache);
   * multiple engines with different active versions coexist in one
@@ -17,21 +18,20 @@ tiles into the executable.  Because every entry owns its trace cache,
 Keys are derived from the same tile dictionaries the engine's level
 tables produce (``VersionSet`` selections or ``DEFAULT_LEVEL_TILES``), so
 the cache holds at most one entry per distinct code version (<= NUM_LEVELS
-per engine).  Memory footprint: one traced+compiled prefill per prompt
-length warmed plus one decode executable per entry, one chunked-prefill
-executable per (entry, chunk bucket) — the serving admission path, which
-is why mixed-length traffic never retraces after warmup — plus one fused
-quantum-decode executable per (entry, K-bucket) actually used and, for
-speculative engines, one verify executable per (entry, K-bucket,
-draft-depth).
+per engine).  Memory footprint: one decode executable per entry, one
+chunked-prefill executable per (entry, chunk bucket) — the serving
+admission path, which is why mixed-length traffic never retraces after
+warmup — plus one fused quantum-decode executable per (entry, K-bucket)
+actually used and, for speculative engines, one verify executable per
+(entry, K-bucket, draft-depth).
 
 Donation: the decode and quantum executables donate their cache argument
 (``donate_argnums``), so every step updates the KV/SSM buffers in place
 instead of allocating a fresh cache — the caller must treat the cache it
-passed in as consumed and adopt the returned one.  The prefill callable
-deliberately does NOT donate: the engine reuses one pristine cache row
-for every admission, and donating it would invalidate that row after the
-first prefill.
+passed in as consumed and adopt the returned one.  The prefill-chunk
+callable deliberately does NOT donate: the engine reuses one pristine
+cache row for every admission, and donating it would invalidate that
+row after the first prefill.
 
 Paged engines need no special handling here: the per-slot ``page_table``
 rides *inside* the cache pytree, so every executable this cache holds is
@@ -108,13 +108,13 @@ class VersionEntry:
     """One code version: jitted executables with the tiles baked in."""
     key: tuple
     tiles: dict[str, dict]
-    prefill: Callable          # (params, tokens (1,L), row_cache) -> ...
     decode: Callable           # (params, {"tokens": (B,)}, cache, t) -> ...
     # bucketed prefill quantum: (params, tokens (1,C), row_cache,
-    #   t0, valid_len) -> (logits, row_cache).  One trace per chunk
-    #   bucket C — t0/valid_len are traced, so mixed-length traffic
-    #   shares the bucket's executable instead of retracing per length.
-    prefill_chunk: Callable = None
+    #   t0, valid_len) -> (logits, row_cache, routing counts).  One trace
+    #   per chunk bucket C — t0/valid_len are traced, so mixed-length
+    #   traffic shares the bucket's executable instead of retracing per
+    #   length.
+    prefill_chunk: Callable
     # K-bucket -> AOT-compiled fused quantum decode
     #   (params, tokens (B,), cache, pos (B,), n_left (B,)) -> (block, cache, pos)
     quanta: dict[int, Callable] = dataclasses.field(default_factory=dict)
@@ -177,12 +177,6 @@ class VersionCache:
         # when kernels.dispatch reads the overrides.  Compiled re-runs never
         # enter it, so the process-global override table is irrelevant to
         # this entry's numerics — and the trace counter stays honest.
-        def prefill(params, tokens, row_cache):
-            self.traces += 1
-            with span(VCACHE_BUILD, kind="prefill", bucket=tokens.shape[1]), \
-                    dispatch.tile_context(snap):
-                return model.prefill(params, {"tokens": tokens}, row_cache)
-
         def decode(params, inputs, cache, t):
             self.traces += 1
             with span(VCACHE_BUILD, kind="decode", bucket=1), \
@@ -197,10 +191,10 @@ class VersionCache:
                                            row_cache, t0, valid_len)
 
         # decode donates its cache (in-place KV/SSM update; the engine
-        # adopts the returned cache every step); prefill and
-        # prefill_chunk must NOT — their cache argument may be the
-        # shared pristine row (see module docstring)
-        return VersionEntry(key=key, tiles=snap, prefill=jax.jit(prefill),
+        # adopts the returned cache every step); prefill_chunk must NOT —
+        # its cache argument may be the shared pristine row (see module
+        # docstring)
+        return VersionEntry(key=key, tiles=snap,
                             decode=jax.jit(decode, donate_argnums=(2,)),
                             prefill_chunk=jax.jit(prefill_chunk))
 

@@ -6,7 +6,10 @@ interference levels diverge under asymmetric load — the lightly-loaded
 engine sees its heavy co-runner's pressure, not its own; (3) the
 calibrated LinearProxy agrees with the oracle on calibration data, so
 routing online decisions through it is sound; (4) a smoke co-location
-serve completes in Pallas interpret mode with per-engine version caches.
+serve completes in Pallas interpret mode with per-engine version caches;
+(5) every stream a co-located serve retires equals the plain
+per-request greedy reference; (6) paged engines behind the cluster share
+a tenant's common prompt prefix through the page pool's prefix index.
 """
 import numpy as np
 import pytest
@@ -16,7 +19,10 @@ from repro.core.interference import (Interference, calibrate_proxy,
                                      read_counters, synthesize_counters)
 from repro.core.scheduler import ModelWisePolicy, PremaPolicy, VeltairPolicy
 from repro.kernels import dispatch
-from repro.serving import ClusterRuntime, Workload, build_cluster, cluster_plans
+from repro.serving import (AdmissionController, ClusterRuntime, Workload,
+                           build_cluster, cluster_plans)
+
+from greedy_ref import manual_greedy
 
 HW = cm.CPU_3990X
 ARCHS = ["gemma-2b", "mamba2-780m"]
@@ -172,3 +178,47 @@ def test_cluster_smoke_interpret_mode(plans, cluster_factory):
     assert m.aggregate.qos_rate >= 0.0
     for t in tenants:
         assert len(t.engine.version_cache) >= 1
+
+
+def test_cluster_serve_matches_per_request_reference(cluster_factory):
+    """The loop the benchmark runs: a tiered bursty serve of two
+    co-located families (linear KV, SSM state) with mixed prompt lengths
+    retires every request with the reference's tokens."""
+    tenants = cluster_factory()
+    wl = Workload.bursty(ARCHS, 300, 8, prompt_len=9, max_new_tokens=6,
+                         seed=8, prompt_len_spread=5,
+                         tiers={ARCHS[0]: "interactive",
+                                ARCHS[1]: "batch"})
+    runtime = ClusterRuntime(tenants, VeltairPolicy(HW), HW)
+    m = runtime.serve(wl)
+    assert m.aggregate.n_queries == wl.n_queries
+    assert set(runtime.outputs) == set(range(wl.n_queries))
+    prompts = runtime.tenant_prompts(wl)
+    lens = wl.prompt_lengths()
+    by_name = {t.name: t.engine for t in tenants}
+    for rid, (_, name) in enumerate(sorted(wl.arrivals)):
+        eng = by_name[name]
+        want = manual_greedy(eng.model, eng.params,
+                             prompts[name][rid, :lens[rid]],
+                             wl.max_new_tokens, eng.max_len)
+        assert runtime.outputs[rid] == want, (rid, name)
+    assert sum(m.prefill_quanta.values()) >= wl.n_queries
+    assert max(m.tokens_per_sync.values()) > 1.0, "fused quanta ran"
+
+
+def test_cluster_paged_engines_share_prompt_prefixes():
+    """Each tenant's prompts open with one system prompt
+    (``shared_prefix_len``); requests resident together on a paged
+    engine borrow its published pages instead of prefilling them."""
+    archs = ["gemma-2b", "starcoder2-3b"]
+    tenants = build_cluster(archs, HW, batch_slots=2, max_len=32,
+                            page_size=8)
+    runtime = ClusterRuntime(tenants, VeltairPolicy(HW), HW,
+                             admission=AdmissionController())
+    wl = Workload.bursty(archs, 200.0, 16, prompt_len=12, max_new_tokens=4,
+                         seed=5, shared_prefix_len=10,
+                         tiers={archs[0]: "interactive", archs[1]: "batch"})
+    m = runtime.serve(wl)
+    assert m.aggregate.n_queries + m.aggregate.shed_queries == wl.n_queries
+    assert sum(s["shared_hits"] for s in m.page_stats.values()) >= 1, \
+        m.page_stats

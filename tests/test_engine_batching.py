@@ -101,7 +101,7 @@ def test_full_level_sweep_after_warmup_zero_retraces(setup):
     cfg, _, params, prompts = setup
     from repro.core import cost_model as cm
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN)
-    engine.warmup(prompt_lens=tuple(len(p) for p in prompts))
+    engine.warmup()
     vc = engine.version_cache
     traces0, misses0 = vc.traces, vc.misses
     switches0 = engine.level_switches
@@ -125,7 +125,7 @@ def test_interpret_mode_flips_hit_distinct_version_entries(setup):
     cfg, _, params, prompts = setup
     dispatch.set_mode("interpret")
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN)
-    engine.warmup(prompt_lens=(len(prompts[0]),), levels=[0.0, 1.0])
+    engine.warmup(levels=[0.0, 1.0])
     vc = engine.version_cache
     assert len(vc) == 3                 # baseline {} + two tile tables
     traces0, misses0 = vc.traces, vc.misses

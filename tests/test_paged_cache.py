@@ -2,16 +2,20 @@
 path shipped without), paged-vs-dense token identity under staggered
 mixed-length admissions (XLA + Pallas interpret), page-pool admission
 pressure (deferred admissions are counted, never silent), cross-request
-prefix sharing with copy-on-write, and the free-page headroom clamp the
-SLO scheduler consults before sizing a decode quantum."""
+prefix sharing with copy-on-write, the free-page headroom clamp the
+SLO scheduler consults before sizing a decode quantum, and the
+concurrency paging buys at an equal cache budget."""
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_reduced_config
+from repro.core import cost_model as cm
 from repro.core.qos import DEFAULT_TIERS
+from repro.core.scheduler import VeltairPolicy
 from repro.kernels import dispatch
 from repro.models import build_model
+from repro.serving import OnlineRuntime, Workload, build_paper_plans
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.paging import TRASH_PAGE, PagePool
 from repro.serving.slo import AdmissionController, SloEntry, pick_quantum
@@ -291,3 +295,34 @@ def test_page_pool_refcounts_and_commitment():
     pool.release(owned[0])
     assert pool.lookup(chain, toks) is None         # refcount 0 unpublishes
     assert pool.free_pages == 1
+
+
+def test_paged_concurrency_at_equal_cache_budget(setup):
+    """Memory as a scheduling dimension: a dense engine pins max_len
+    positions per slot, so a 64-token cache budget holds 2 rows however
+    short they are; a paged engine draws 8-token pages from the same 64
+    tokens on demand and commits only each request's worst case, so the
+    same bursty traffic runs at least 1.5x as many rows at once, with
+    the same tokens (virtual time: a count, not a speed)."""
+    cfg, _, params = setup
+    hw = cm.CPU_3990X
+    tenants = ["resnet50", "googlenet"]
+    plans = build_paper_plans(tenants, hw)
+    budget = 2 * MAX_LEN
+    wl = Workload.bursty(tenants, 400.0, 20, prompt_len=8, max_new_tokens=3,
+                         seed=5, prompt_len_spread=3, shared_prefix_len=PAGE)
+    peak, resident, outputs = {}, {}, {}
+    for name, kw in (("dense", dict(batch_slots=budget // MAX_LEN)),
+                     ("paged", dict(batch_slots=6, page_size=PAGE,
+                                    n_pages=budget // PAGE))):
+        eng = ServingEngine(cfg, params, max_len=MAX_LEN, **kw)
+        runtime = OnlineRuntime(eng, VeltairPolicy(hw), plans, hw)
+        m = runtime.serve(wl)
+        assert m.n_queries == wl.n_queries
+        peak[name] = eng.peak_active_slots
+        resident[name] = (eng.pool.peak_used * PAGE if eng.paged
+                          else eng.slots * MAX_LEN)
+        outputs[name] = runtime.outputs
+    assert all(r <= budget for r in resident.values()), resident
+    assert peak["paged"] >= 1.5 * peak["dense"], peak
+    assert outputs["paged"] == outputs["dense"]

@@ -1,10 +1,11 @@
 """Chunked, length-bucketed prefill quanta (ISSUE-5 acceptance).
 
-(1) Chunked+padded admission is token-for-token identical to the
-monolithic prefill path under staggered admissions and mixed prompt
-lengths — across every cache family the engines serve (linear KV,
-SSM state, RG-LRU recurrence + window ring buffer); the ring-buffer
-wrap (prompt longer than the attention window) is exact too.
+(1) Chunked+padded admission is token-for-token identical to a plain
+per-request ``Model.prefill`` + ``Model.decode_step`` greedy loop under
+staggered admissions and mixed prompt lengths — across every cache
+family the engines serve (linear KV, SSM state, RG-LRU recurrence +
+window ring buffer); the ring-buffer wrap (prompt longer than the
+attention window) is exact too.
 (2) A ``prompt_len_spread > 0`` workload served after ``warmup()``
 performs ZERO jax retraces (the compiled prefill shapes are the bucket
 table, not the prompt-length distribution) — xla and interpret modes.
@@ -28,6 +29,8 @@ from repro.kernels import dispatch
 from repro.models import build_model
 from repro.serving import OnlineRuntime, Workload, build_paper_plans
 from repro.serving.engine import Request, ServingEngine
+
+from greedy_ref import manual_greedy
 
 HW = cm.CPU_3990X
 TENANTS = ["resnet50", "googlenet"]
@@ -82,21 +85,19 @@ def _staggered(engine, prompts):
 
 
 # ---------------------------------------------------------------------------
-# (1) token identity: chunked+bucketed == monolithic
+# (1) token identity: chunked+bucketed == the plain per-request reference
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("arch", ["gemma-2b", "mamba2-780m",
                                   "recurrentgemma-2b"])
 def test_chunked_prefill_token_identity(models, arch):
-    cfg, _, params = models[arch]
+    cfg, model, params = models[arch]
     prompts = _prompts(cfg, PROMPT_LENS)
-    mono = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
-                         chunked_prefill=False)
-    want = _staggered(mono, prompts)
+    want = [manual_greedy(model, params, p, N_NEW, MAX_LEN) for p in prompts]
     chunk = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                           prefill_chunk_len=8)
     got = _staggered(chunk, prompts)
     for w, g in zip(want, got):
-        assert g.output == w.output, (arch, g.rid, g.output, w.output)
+        assert g.output == w, (arch, g.rid, g.output, w)
     # the chunked engine really went through the bucketed path
     assert chunk.prefill_chunks > len(prompts)       # 13 and 19 split
     assert chunk.prefill_pad_tokens > 0              # 13, 19, 5 padded
@@ -104,17 +105,15 @@ def test_chunked_prefill_token_identity(models, arch):
 
 
 def test_chunked_prefill_token_identity_interpret(models):
-    cfg, _, params = models["gemma-2b"]
+    cfg, model, params = models["gemma-2b"]
     dispatch.set_mode("interpret")
     prompts = _prompts(cfg, PROMPT_LENS[:2])
-    mono = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
-                         chunked_prefill=False)
-    want = _staggered(mono, prompts)
+    want = [manual_greedy(model, params, p, N_NEW, MAX_LEN) for p in prompts]
     chunk = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                           prefill_chunk_len=8)
     got = _staggered(chunk, prompts)
     for w, g in zip(want, got):
-        assert g.output == w.output, (g.rid, g.output, w.output)
+        assert g.output == w, (g.rid, g.output, w)
 
 
 def test_window_ring_wrap_chunked_matches_monolithic(models):
@@ -163,7 +162,7 @@ def test_mixed_length_serve_zero_retraces_after_warmup(models, plans, mode):
     dispatch.set_mode(mode)
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                            prefill_chunk_len=8)
-    engine.warmup()                      # note: NO per-length prompt_lens
+    engine.warmup()                      # buckets only, no prompt lengths
     vc = engine.version_cache
     traces0, misses0 = vc.traces, vc.misses
     runtime = OnlineRuntime(engine, VeltairPolicy(HW), plans, HW)
@@ -233,28 +232,42 @@ def test_long_prompt_admission_advances_clock(models, plans):
 
 
 def test_prefill_chunks_interleave_with_decode(models, plans):
-    """Two same-length prompts back to back: the first request's decode
-    must complete while the second prompt is still prefilling — a long
-    admission no longer stalls a co-resident tenant's decode.
-
-    Pinned to the FIFO scheduler: strict prefill/decode alternation is
-    the mechanism under test.  The SLO scheduler deliberately makes a
-    different (deadline-driven) choice here — its preemption ordering is
-    covered by tests/test_slo_scheduling.py."""
+    """Prefill chunks are quanta the earliest-deadline pick orders
+    against decode quanta.  Two same-length untiered prompts back to
+    back have equal deadlines: the pick drains prefill first, oldest
+    prompt first, and both rows then decode in one batch.  Give the
+    first prompt the interactive tier and the second the batch tier, and
+    the interactive request's decode completes before the batch prompt's
+    prefill does — a co-resident admission no longer stalls it."""
     cfg, _, params = models["gemma-2b"]
-    engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
-                           prefill_chunk_len=4)
-    runtime = OnlineRuntime(engine, FixedBlockPolicy(HW, 1), plans, HW,
-                            scheduler="fifo")
-    wl = Workload([(0.0, "resnet50"), (0.0, "resnet50")],
-                  prompt_len=12, max_new_tokens=2)
-    runtime.serve(wl)
-    assert len(runtime.records) == 2
-    first, second = sorted(runtime.records, key=lambda r: r.finish)
+
+    def serve(tenants, tiers=None):
+        engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
+                               prefill_chunk_len=4)
+        runtime = OnlineRuntime(engine, FixedBlockPolicy(HW, 1), plans, HW)
+        runtime.serve(Workload([(0.0, t) for t in tenants], prompt_len=12,
+                               max_new_tokens=2, tiers=tiers))
+        assert len(runtime.records) == 2
+        assert runtime.prefill_quanta == 6          # 3 chunks per prompt
+        first, second = sorted(runtime.records,
+                               key=lambda r: (r.finish, r.ttft_s))
+        return runtime, first, second
+
+    runtime, first, second = serve(["resnet50", "resnet50"])
+    dt = runtime.step_dt
+    assert [ev[0] for ev in runtime.sched_trace] == ["prefill"] * 6 + \
+        ["decode"] * 2
+    assert first.ttft_s == pytest.approx(3 * dt)
+    assert second.ttft_s == pytest.approx(6 * dt)
+    assert first.finish == second.finish == pytest.approx(8 * dt)
+
+    _, first, second = serve(["resnet50", "googlenet"],
+                             {"resnet50": "interactive",
+                              "googlenet": "batch"})
+    assert first.tier == "interactive"
     assert first.ttft_s < second.ttft_s
     # the first request finished before the second's prefill completed
     assert first.finish < second.arrival + second.ttft_s
-    assert runtime.prefill_quanta == 6          # 3 chunks per prompt
 
 
 # ---------------------------------------------------------------------------

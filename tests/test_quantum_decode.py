@@ -262,7 +262,7 @@ def test_level_sweep_after_warmup_traces_flat_with_quanta(setup):
     cfg, _, params, prompts = setup
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                            quantum_buckets=(2, 4))
-    engine.warmup(prompt_lens=(len(prompts[0]),))
+    engine.warmup()
     vc = engine.version_cache
     for entry in vc._entries.values():
         assert set(entry.quanta) == {2, 4}, "buckets prebuilt at warmup"
@@ -310,7 +310,7 @@ def test_warmup_mid_serving_preserves_inflight_state(setup):
     engine.admit_request(req, drain=True)
     engine.step()
     engine.step()
-    engine.warmup(prompt_lens=(len(prompts[0]),))   # mid-serving warmup
+    engine.warmup()   # mid-serving warmup
     while not req.done:
         engine.step_quantum(4)
     assert req.output[:7] == want[:7]

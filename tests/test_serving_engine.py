@@ -7,19 +7,7 @@ from repro.configs import get_reduced_config
 from repro.models import build_model
 from repro.serving.engine import Request, ServingEngine
 
-
-def _manual_greedy(model, params, prompt, n_new, max_len):
-    cache = model.init_cache(1, max_len)
-    logits, cache = model.prefill(params, {"tokens": prompt[None]}, cache)
-    out = [int(jnp.argmax(logits[0]))]
-    t = prompt.shape[0]
-    for _ in range(n_new):
-        logits, cache = model.decode_step(
-            params, {"tokens": jnp.asarray([out[-1]], jnp.int32)}, cache,
-            jnp.int32(t))
-        out.append(int(jnp.argmax(logits[0])))
-        t += 1
-    return out
+from greedy_ref import manual_greedy
 
 
 def test_engine_matches_manual_decode():
@@ -29,7 +17,7 @@ def test_engine_matches_manual_decode():
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, 10), jnp.int32)
     n_new = 5
-    want = _manual_greedy(model, params, prompt, n_new, 32)
+    want = manual_greedy(model, params, prompt, n_new, 32)
 
     engine = ServingEngine(cfg, params, batch_slots=2, max_len=32)
     req = Request(rid=0, prompt=np.asarray(prompt), max_new_tokens=n_new)

@@ -7,16 +7,15 @@ inter-arrival variance at equal offered load.
 from the one shared summarize(), qps_at_qos as the headline rate.
 (3) Preemption ordering: an interactive-tier admission arriving
 mid-stream runs its first prefill chunk before any further batch-tier
-decode quantum — and token streams stay identical to the FIFO
-schedule's per-request outputs (scheduling reorders, never corrupts).
+decode quantum — and every served stream stays identical to a plain
+per-request greedy reference (scheduling reorders, never corrupts).
 (4) Admission control: shed/deferred queries are counted, never
 silently dropped.
-(5) API redesign: ``add_request`` deprecates into ``admit_request``,
-``step()``/``step_quantum`` ride the unified begin/finish path, and
-``run_to_completion`` defaults to fused dispatch with identical tokens.
+(5) The unified serving API: ``step()``/``step_quantum`` ride the
+begin/finish path, and ``run_to_completion`` defaults to fused dispatch
+with identical tokens.
 """
 import math
-import warnings
 
 import numpy as np
 import pytest
@@ -27,8 +26,10 @@ from repro.core.qos import (DEFAULT_TIERS, TIER_ORDER, QueryRecord,
 from repro.core.scheduler import FixedBlockPolicy, VeltairPolicy
 from repro.serving import (AdmissionController, OnlineRuntime, Workload,
                            build_paper_plans, diurnal_workload,
-                           gamma_poisson_workload)
+                           gamma_poisson_workload, synth_prompts)
 from repro.serving.engine import Request, ServingEngine
+
+from greedy_ref import manual_greedy
 
 HW = cm.CPU_3990X
 TENANTS = ["resnet50", "googlenet"]
@@ -207,29 +208,25 @@ def test_interactive_prefill_preempts_batch_decode(plans, engine_factory):
 
 
 def test_slo_and_fifo_schedules_are_token_identical(plans, engine_factory):
-    """Scheduling reorders quanta, never corrupts streams: per-request
-    outputs under the SLO schedule match the FIFO schedule exactly."""
+    """Scheduling reorders quanta, never corrupts streams: every
+    per-request output of a tiered bursty serve equals the plain
+    one-request-at-a-time greedy reference."""
     wl = Workload.bursty(TENANTS, 400, 16, prompt_len=6, max_new_tokens=3,
                          seed=6, prompt_len_spread=3, tiers=TIERS)
-    rt_slo = OnlineRuntime(engine_factory(), VeltairPolicy(HW), plans, HW,
-                           scheduler="slo")
-    rt_fifo = OnlineRuntime(engine_factory(), VeltairPolicy(HW), plans, HW,
-                            scheduler="fifo")
-    m_slo = rt_slo.serve(wl)
-    m_fifo = rt_fifo.serve(wl)
-    assert m_slo.n_queries == m_fifo.n_queries == wl.n_queries
-    assert set(rt_slo.outputs) == set(rt_fifo.outputs)
-    for rid in rt_fifo.outputs:
-        assert rt_slo.outputs[rid] == rt_fifo.outputs[rid], rid
-    # orderings did actually differ somewhere (otherwise the comparison
-    # proves nothing) — prefill pick or admission order
-    assert rt_slo.sched_trace != rt_fifo.sched_trace
-
-
-def test_bad_scheduler_name_rejected(plans, engine_factory):
-    with pytest.raises(ValueError):
-        OnlineRuntime(engine_factory(), VeltairPolicy(HW), plans, HW,
-                      scheduler="lifo")
+    engine = engine_factory()
+    rt = OnlineRuntime(engine, VeltairPolicy(HW), plans, HW)
+    m = rt.serve(wl)
+    assert m.n_queries == wl.n_queries
+    assert set(rt.outputs) == set(range(wl.n_queries))
+    prompts = synth_prompts(wl.n_queries, wl.prompt_len,
+                            engine.cfg.vocab_size, wl.seed)
+    lens = wl.prompt_lengths()
+    assert len(set(lens)) > 1, "spread must mix lengths"
+    for rid, out in rt.outputs.items():
+        want = manual_greedy(engine.model, engine.params,
+                             prompts[rid, :lens[rid]], wl.max_new_tokens,
+                             engine.max_len)
+        assert out == want, rid
 
 
 # ---------------------------------------------------------------------------
@@ -281,23 +278,6 @@ def test_tier_qos_ordering_under_overload(plans, engine_factory):
 # ---------------------------------------------------------------------------
 # (5) the unified serving API
 # ---------------------------------------------------------------------------
-def test_add_request_deprecates_into_admit_request(engine_factory):
-    engine = engine_factory()
-    rng = np.random.default_rng(0)
-    req = Request(rid=0, prompt=rng.integers(
-        0, engine.cfg.vocab_size, 6).astype(np.int32), max_new_tokens=2)
-    with pytest.warns(DeprecationWarning):
-        assert engine.add_request(req)
-    assert req.output, "shim must still drain the prefill"
-    # the replacement spelling does the same without warning
-    engine2 = engine_factory()
-    req2 = Request(rid=0, prompt=req.prompt, max_new_tokens=2)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert engine2.admit_request(req2, drain=True)
-    assert req2.output == req.output
-
-
 def test_run_to_completion_fused_matches_per_step(engine_factory):
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, 4096, n).astype(np.int32) for n in (5, 9, 7)]

@@ -157,7 +157,7 @@ def test_spec_zero_retraces_after_warmup(setup):
     cfg, _, params, prompts = setup
     eng = ServingEngine(cfg, params, batch_slots=2, max_len=MAX_LEN,
                         speculative=True)
-    eng.warmup(prompt_lens=tuple(len(p) for p in prompts))
+    eng.warmup()
     if eng._spec_enabled:
         for entry in eng.version_cache._entries.values():
             assert entry.spec, "verify executables prebuilt at warmup"
